@@ -169,6 +169,9 @@ def fig19_cache_sweep(ctx):
 
 
 def main() -> None:
+    from repro.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="gate mode only, 3 budgets")

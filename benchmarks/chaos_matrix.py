@@ -142,6 +142,9 @@ def serve_hammer(ctx, *, p_eio, seed, n_requests=64):
 
 
 def main() -> None:
+    from repro.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="small matrix (CI smoke): gate mode, depth 1, "

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import EngineConfig, GateANNEngine, SearchConfig, recall_at_k
-from repro.core.graph import VamanaGraph, build_vamana
+from repro.core.graph import BUILD_REVISION, VamanaGraph, build_vamana
 from repro.core.io_model import DEFAULT_COST_MODEL
 from repro.data import (
     filtered_ground_truth,
@@ -29,7 +29,9 @@ from repro.data import (
 )
 
 REPO_ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
-CACHE_DIR = os.path.join(REPO_ROOT, "results", "bench_cache")
+# cached graphs and indexes live under the build revision that made them,
+# so a change to build_vamana's output never serves a stale artifact
+CACHE_DIR = os.path.join(REPO_ROOT, "results", "bench_cache", f"rev{BUILD_REVISION}")
 
 # version stamp for every benchmark JSON artifact (BENCH_*.json) — bump
 # on any field rename/removal so nightly consumers can fail loudly

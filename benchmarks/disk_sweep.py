@@ -267,6 +267,9 @@ def sweep_pipeline(ctx, *, max_depth=4, search_l=100, repeats=3):
 
 
 def main() -> None:
+    from repro.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="gate+post only, budgets (0, 256)")

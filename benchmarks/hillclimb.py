@@ -30,6 +30,9 @@ import time
 
 
 def main():
+    from repro.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--shape", required=True)
@@ -48,27 +51,28 @@ def main():
     from repro.configs.base import ALL_SHAPES
     from repro.launch.dryrun import lower_cell
     from benchmarks.roofline import (
-        HBM_BW, LINK_BW, PEAK_FLOPS, analytic_collective_bytes,
-        model_bytes_per_device, model_flops_per_device,
+        DRYRUN_KIND, analytic_collective_bytes, model_bytes_per_device,
+        model_flops_per_device, peaks,
     )
 
     shape = next(s for s in ALL_SHAPES if s.name == args.shape)
     t0 = time.perf_counter()
     _, compiled, report, hlo = lower_cell(args.arch, shape, donate=donate)
-    t_c = report["flops_per_device"] / PEAK_FLOPS
-    hlo_m = report["hbm_bytes_per_device"] / HBM_BW
-    ana_m = model_bytes_per_device(report, variants) / HBM_BW
+    pk = peaks(DRYRUN_KIND)
+    t_c = report["flops_per_device"] / pk["flops"]
+    hlo_m = report["hbm_bytes_per_device"] / pk["hbm_bw"]
+    ana_m = model_bytes_per_device(report, variants) / pk["hbm_bw"]
     t_m = min(hlo_m, ana_m)
     # collective: HLO parse is f32-normalized on the CPU backend (bf16
     # widened) — report both the parse and the dtype-corrected model
-    t_x_hlo = report["collective_bytes_total"] / LINK_BW
+    t_x_hlo = report["collective_bytes_total"] / pk["link_bw"]
     coll_model = analytic_collective_bytes(report, variants)
     # two corrected estimates: (a) analytic structure x logical dtypes,
     # (b) HLO-parsed structure x bf16 correction (CPU f32-normalizes all
     # compute tensors; under cast_early everything big is logically bf16).
     dtype_factor = 0.5 if "cast_early" in variants else 1.0
     t_x_corrected_parse = t_x_hlo * dtype_factor
-    t_x = min(coll_model["total"] / LINK_BW, t_x_corrected_parse)
+    t_x = min(coll_model["total"] / pk["link_bw"], t_x_corrected_parse)
     entry = {
         "arch": args.arch,
         "shape": args.shape,

@@ -2,10 +2,11 @@
 
 For every (arch x shape x mesh) cell this derives the three terms:
 
-  compute    = HLO_FLOPs_per_device / peak_FLOPs        (197 TF/s bf16, v5e)
-  memory     = HLO_bytes_per_device / HBM_bw            (819 GB/s)
-  collective = collective_bytes_per_device / link_bw    (50 GB/s/link, 1 link
-                                                         conservative)
+  compute    = HLO_FLOPs_per_device / peak_FLOPs
+  memory     = HLO_bytes_per_device / HBM_bw
+  collective = collective_bytes_per_device / link_bw    (1 link, conservative)
+
+with the peaks of the chip the dry run targets (``PEAKS[DRYRUN_KIND]``).
 
 HLO_FLOPs / bytes / collective bytes come from the loop-aware parse of the
 compiled partitioned HLO (repro.launch.hlo_analysis) — XLA's own
@@ -39,9 +40,29 @@ import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-PEAK_FLOPS = 197e12  # bf16 per chip
-HBM_BW = 819e9  # B/s
-LINK_BW = 50e9  # B/s per ICI link (conservative single-link)
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``.  A device
+# missing here is an error (``peaks``), never a default.
+#   "TPU v5 lite" = TPU v5e.  Source: Google Cloud documentation, "TPU v5e":
+#   197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s ICI per chip
+#   (4 links -> 50 GB/s per link).
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "link_bw": 50e9},
+}
+# the dry run (repro.launch.dryrun) lowers its cells for a v5e mesh
+DRYRUN_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> dict:
+    """Peaks of one chip of this kind: flops (bf16 FLOP/s), hbm_bw and
+    link_bw (B/s).  Raises ValueError for a kind with no published row."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device_kind {device_kind!r}; add a "
+            f"row with its source to benchmarks/roofline.py PEAKS "
+            f"(known: {sorted(PEAKS)})"
+        ) from None
 
 
 def _param_split(cfg):
@@ -210,16 +231,17 @@ def suggestion(dom: str, rep: dict) -> str:
 
 
 def analyze_cell(rep: dict) -> dict:
-    t_c = rep["flops_per_device"] / PEAK_FLOPS
+    pk = peaks(DRYRUN_KIND)
+    t_c = rep["flops_per_device"] / pk["flops"]
     # memory: min(parsed-HLO bytes, analytic model) — the parse is an upper
     # bound because CPU-backend fusion is weaker than TPU's (EXPERIMENTS §R)
-    hlo_m = rep.get("hbm_bytes_per_device", 0.0) / HBM_BW
-    ana_m = model_bytes_per_device(rep) / HBM_BW
+    hlo_m = rep.get("hbm_bytes_per_device", 0.0) / pk["hbm_bw"]
+    ana_m = model_bytes_per_device(rep) / pk["hbm_bw"]
     t_m = min(hlo_m, ana_m) if ana_m else hlo_m
     # dtype-corrected collective model (CPU HLO is f32-normalized); the
     # HLO parse bounds it from above and verifies the op structure.
-    t_x_model = analytic_collective_bytes(rep)["total"] / LINK_BW
-    t_x_hlo = rep.get("collective_bytes_total", 0.0) / LINK_BW
+    t_x_model = analytic_collective_bytes(rep)["total"] / pk["link_bw"]
+    t_x_hlo = rep.get("collective_bytes_total", 0.0) / pk["link_bw"]
     t_x = min(t_x_model, t_x_hlo) if t_x_model else t_x_hlo
     terms = {"compute": t_c, "memory": t_m, "collective": t_x}
     dom = max(terms, key=terms.get)
@@ -239,7 +261,7 @@ def analyze_cell(rep: dict) -> dict:
         "model_flops_per_dev": mf,
         "useful_ratio": (mf / rep["flops_per_device"]) if rep["flops_per_device"] else 0.0,
         "roofline_fraction": (t_c / bound) if bound else 0.0,
-        "mfu_bound": (mf / PEAK_FLOPS / bound) if bound and mf else 0.0,
+        "mfu_bound": (mf / pk["flops"] / bound) if bound and mf else 0.0,
         "suggestion": suggestion(dom, rep),
     }
 
@@ -288,6 +310,10 @@ def kernels_sweep(args) -> int:
     from repro.kernels import fused_traversal as ft
     from repro.kernels.backend import supports_compiled_pallas
 
+    dev = jax.devices()[0]
+    # an unknown chip fails here, before the sweep; --no-roofline keeps
+    # the parity and timing rows on a device with no published peaks
+    peak = None if args.no_roofline else peaks(dev.device_kind)
     b, l, w = args.batch, args.search_l, args.beam
     r, r_max = args.degree, args.r_max
     c, k = args.pq_chunks, args.pq_k
@@ -319,44 +345,51 @@ def kernels_sweep(args) -> int:
     t_unfused = bench(lambda: unfused(state))
     speedup = t_unfused / t_fused if t_fused > 0 else 0.0
 
-    # roofline placement: ADC one-hot contraction dominates FLOPs
-    # (B·C·M·K MACs); the working set is the VMEM-resident round state
-    flops = 2.0 * b * c * m * k
-    bytes_rt = 4.0 * b * (
-        l * 4 + m * (2 + c) + c * k  # frontier + candidates/codes + lut
-    )
-    t_c, t_m = flops / PEAK_FLOPS, bytes_rt / HBM_BW
     rows = [
         {"name": "fused_parity", "derived": 1.0 if parity else 0.0},
         {"name": "fused_speedup", "derived": speedup},
         {"name": "fused_compiled", "derived": 1.0 if compiled else 0.0},
         {"name": "fused_us", "derived": t_fused * 1e6},
         {"name": "unfused_us", "derived": t_unfused * 1e6},
-        {"name": "stage_flops", "derived": flops},
-        {"name": "stage_bytes", "derived": bytes_rt},
-        {"name": "stage_intensity", "derived": flops / bytes_rt},
-        {"name": "stage_roofline_bound_us",
-         "derived": max(t_c, t_m) * 1e6},
     ]
+    if peak is not None:
+        # roofline placement: ADC one-hot contraction dominates FLOPs
+        # (B·C·M·K MACs); the working set is the VMEM-resident round state
+        flops = 2.0 * b * c * m * k
+        bytes_rt = 4.0 * b * (
+            l * 4 + m * (2 + c) + c * k  # frontier + candidates/codes + lut
+        )
+        t_c, t_m = flops / peak["flops"], bytes_rt / peak["hbm_bw"]
+        rows += [
+            {"name": "stage_flops", "derived": flops},
+            {"name": "stage_bytes", "derived": bytes_rt},
+            {"name": "stage_intensity", "derived": flops / bytes_rt},
+            {"name": "stage_roofline_bound_us",
+             "derived": max(t_c, t_m) * 1e6},
+        ]
     print("| metric | value |")
     print("|---|---|")
     for row in rows:
         print(f"| {row['name']} | {row['derived']:.6g} |")
     print(
         f"# shapes: B={b} L={l} W={w} M={m} C={c} K={k} "
-        f"backend={jax.default_backend()} "
+        f"device={dev.platform}/{dev.device_kind}x{len(jax.devices())} "
         f"mode={'compiled' if compiled else 'interpret'}"
     )
     if args.json:
         with open(args.json, "w") as f:
             json.dump({"rows": rows, "shape": {
                 "b": b, "l": l, "w": w, "m": m, "c": c, "k": k,
-                "backend": jax.default_backend(),
+                "platform": dev.platform, "device_kind": dev.device_kind,
+                "device_count": len(jax.devices()),
             }}, f, indent=1)
     return 0 if parity else 1
 
 
 def main():
+    from repro.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--dryrun-dir", default="results/dryrun")
     ap.add_argument("--format", default="md", choices=["md", "csv"])
@@ -373,6 +406,9 @@ def main():
     ap.add_argument("--pq-chunks", type=int, default=8)
     ap.add_argument("--pq-k", type=int, default=16)
     ap.add_argument("--repeats", type=int, default=20)
+    ap.add_argument("--no-roofline", action="store_true",
+                    help="(--kernels) parity and timing rows only, for a "
+                         "device with no published peaks (the CPU)")
     args = ap.parse_args()
 
     if args.kernels:

@@ -14,6 +14,9 @@ from benchmarks import figures as F
 
 
 def main() -> None:
+    from repro.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--figure", default=None)
     ap.add_argument("--quick", action="store_true",

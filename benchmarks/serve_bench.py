@@ -275,6 +275,9 @@ def pctl_rows(tag: str, lats_s: np.ndarray, qps: float):
 
 
 def main() -> None:
+    from repro.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="small request counts (CI smoke)")

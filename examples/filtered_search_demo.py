@@ -9,6 +9,10 @@ import sys
 
 sys.path.insert(0, "src")
 
+from repro.compile_cache import configure_compile_cache
+
+configure_compile_cache()
+
 import jax.numpy as jnp
 import numpy as np
 

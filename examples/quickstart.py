@@ -6,6 +6,10 @@ import sys, time
 
 sys.path.insert(0, "src")
 
+from repro.compile_cache import configure_compile_cache
+
+configure_compile_cache()
+
 import numpy as np
 
 from repro.core import EngineConfig, GateANNEngine, SearchConfig, recall_at_k

@@ -13,6 +13,10 @@ import time
 
 sys.path.insert(0, "src")
 
+from repro.compile_cache import configure_compile_cache
+
+configure_compile_cache()
+
 import jax
 import numpy as np
 

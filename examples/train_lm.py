@@ -34,6 +34,9 @@ CFG = ModelConfig(
 
 
 def main():
+    from repro.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--batch", type=int, default=8)

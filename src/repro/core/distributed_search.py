@@ -28,10 +28,10 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.frontier import _dedup_mask
+from repro.kernels import ref as kref
 from repro.store import format as idx_format
 
 INVALID = jnp.int32(-1)
@@ -113,8 +113,9 @@ class DistSearchConfig:
 
 
 def _adc(lut, codes_rows):
-    """lut (B, C, K) f32; codes_rows (B, M, C) int32 -> (B, M) f32."""
-    return jnp.take_along_axis(lut.transpose(0, 2, 1), codes_rows, axis=1).sum(-1)
+    """lut (B, C, K) f32; codes_rows (B, M, C) int32 -> (B, M) f32 — the
+    single-host loop's ADC arithmetic, so both loops order alike."""
+    return kref.pq_lookup_gathered_ref(lut, codes_rows)
 
 
 def make_retrieve_step(
@@ -204,7 +205,8 @@ def make_retrieve_step(
                 tunnel_mask = jnp.zeros_like(valid)
 
             vecs, disk_nbrs = fetch(jnp.where(fetch_mask, sel, INVALID))
-            exact = jnp.sum((vecs - queries[:, None, :]) ** 2, axis=-1)
+            diff = vecs - queries[:, None, :]
+            exact = kref.pairwise_sum(diff * diff)  # as core.search._exact_dist
             exact = jnp.where(passes & fetch_mask, exact, INF)
             # results insert (dedup by id, exactly like fr.results_insert)
             cat_i = jnp.concatenate([res_ids, jnp.where(passes & fetch_mask, sel, INVALID)], 1)
@@ -249,13 +251,13 @@ def make_retrieve_step(
 
     qspec = P(batch_axes, None)
     rep = P(None, None)
-    mapped = shard_map(
+    mapped = jax.shard_map(
         step,
         mesh=mesh,
         in_specs=(qspec, P(batch_axes, None, None), rep, rep, P(None),
                   P("model", None), P("model", None), P(), P(batch_axes)),
         out_specs={"ids": qspec, "dists": qspec, "n_ios": P(batch_axes),
                    "n_tunnels": P(batch_axes)},
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(mapped)

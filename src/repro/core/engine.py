@@ -64,7 +64,7 @@ class EngineConfig:
     # engine-wide default for SearchConfig.use_fused_kernel: run stage-A
     # traversal as one fused Pallas pass per round.  Callers passing an
     # explicit search_config keep full control; results are bit-identical
-    # either way (unsupported shapes/backends fall back silently).
+    # either way, and shapes the kernel cannot hold raise ValueError.
     use_fused_kernel: bool = False
     # disk-tier resilience (store/disk.py): transient read errors (EIO /
     # EAGAIN / EINTR / ETIMEDOUT) retry up to io_retries times with

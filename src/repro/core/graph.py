@@ -5,7 +5,8 @@ search the *same* standard Vamana graph (paper §5.1).  We implement:
 
   * ``build_vamana``          — batched two-pass Vamana build
                                 (greedy search for candidates + RobustPrune,
-                                reverse-edge insertion with overflow pruning).
+                                reverse-edge insertion with overflow pruning),
+                                rows written longest edge first (``longest_first_batch``).
   * ``build_filtered_vamana`` — the F-DiskANN baseline: label-aware pruning
                                 and per-label medoid entry points.
   * ``beam_search_batch``     — jitted batched best-first search over
@@ -24,8 +25,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.pq import argmin_f32
+
 INVALID = jnp.int32(-1)
 INF = jnp.float32(3.4e38)
+# bumped whenever build_vamana's output changes (2: rows longest edge
+# first); artifact caches key on it
+BUILD_REVISION = 2
 
 
 class VamanaGraph(NamedTuple):
@@ -44,10 +50,12 @@ def l2_sq(x: jax.Array, y: jax.Array) -> jax.Array:
 
 
 def l2_sq_pairwise(x: jax.Array, y: jax.Array) -> jax.Array:
-    """(Nx, D) x (Ny, D) -> (Nx, Ny)."""
+    """(Nx, D) x (Ny, D) -> (Nx, Ny).  The matmul runs at full f32
+    precision: the TPU default (bf16 operands) would make the expansion
+    cancel away the distances between close points."""
     return (
         jnp.sum(x * x, axis=1, keepdims=True)
-        - 2.0 * x @ y.T
+        - 2.0 * jnp.matmul(x, y.T, precision=jax.lax.Precision.HIGHEST)
         + jnp.sum(y * y, axis=1)[None, :]
     )
 
@@ -55,7 +63,7 @@ def l2_sq_pairwise(x: jax.Array, y: jax.Array) -> jax.Array:
 def find_medoid(vectors: jax.Array) -> jax.Array:
     """Node closest to the dataset centroid (the DiskANN entry point)."""
     centroid = jnp.mean(vectors, axis=0, keepdims=True)
-    return jnp.argmin(l2_sq(vectors, centroid)).astype(jnp.int32)
+    return argmin_f32(l2_sq(vectors, centroid), axis=0).astype(jnp.int32)
 
 
 # ---------------------------------------------------------------------------
@@ -239,7 +247,7 @@ def robust_prune_batch(
 
     def select_one(state, _):
         alive, d_p_cur, out, k = state
-        best = jnp.argmin(jnp.where(alive, d_p_cur, INF), axis=1)  # (B,)
+        best = argmin_f32(jnp.where(alive, d_p_cur, INF), axis=1)  # (B,)
         best_ok = jnp.take_along_axis(jnp.where(alive, d_p_cur, INF), best[:, None], axis=1)[
             :, 0
         ] < INF
@@ -258,6 +266,20 @@ def robust_prune_batch(
         select_one, (valid, d_p, out0, 0), None, length=degree
     )
     return out
+
+
+@jax.jit
+def longest_first_batch(point_ids: jax.Array, rows: jax.Array, vectors: jax.Array) -> jax.Array:
+    """Reorder adjacency rows (B, R): longest edge first, -1 padding last.
+
+    The neighbor store keeps a row's first R_max entries for tunneling.
+    The closest neighbors of a node crowd together, so a tunneled walk
+    over them goes in circles; the long edges are the ones that carry it
+    toward the query.  The set of neighbors is unchanged.
+    """
+    d = l2_sq(vectors[jnp.maximum(rows, 0)], vectors[point_ids][:, None, :])
+    order = jnp.argsort(jnp.where(rows >= 0, -d, INF), axis=1, stable=True)
+    return jnp.take_along_axis(rows, order, axis=1)
 
 
 # ---------------------------------------------------------------------------
@@ -288,7 +310,8 @@ def build_vamana(
     Pass 1 uses alpha=1.0, pass 2 the final alpha — as in DiskANN. Each
     batch: greedy-search every point from the medoid, RobustPrune its
     visited set, install edges, then add reverse edges and re-prune nodes
-    whose degree overflows.
+    whose degree overflows.  Last, every row is put longest edge first
+    (``longest_first_batch``), so any prefix is a usable neighbor store.
     """
     vectors = jnp.asarray(vectors, dtype=jnp.float32)
     n, d = vectors.shape
@@ -349,6 +372,11 @@ def build_vamana(
                     )
                     nbrs[ob] = np.asarray(opr)
 
+    for start in range(0, n, batch_size):
+        batch = np.arange(start, min(start + batch_size, n), dtype=np.int32)
+        if len(batch) < batch_size:
+            batch = np.concatenate([batch, np.full(batch_size - len(batch), batch[0], np.int32)])
+        nbrs[batch] = np.asarray(longest_first_batch(jnp.asarray(batch), jnp.asarray(nbrs[batch]), vectors))
     return VamanaGraph(neighbors=jnp.asarray(nbrs), medoid=jnp.int32(medoid))
 
 

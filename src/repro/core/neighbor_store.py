@@ -2,9 +2,11 @@
 
 Replicates the first ``R_max`` neighbors of each node from the on-disk
 graph into a contiguous fixed-stride array.  Built at load time from the
-unmodified index (Vamana stores neighbors in proximity order, so a prefix
-keeps the closest, most useful routes).  ``R_max`` is a *runtime* knob —
-no index rebuild is ever required to change it (§3.4).
+unmodified index: ``build_vamana`` writes each row longest edge first,
+so a prefix keeps the long routes that carry a tunneled walk toward the
+query rather than the closest neighbors, which crowd together.
+``R_max`` is a *runtime* knob — no index rebuild is ever required to
+change it (§3.4).
 """
 from __future__ import annotations
 
@@ -24,7 +26,7 @@ class NeighborStore:
 
     @classmethod
     def from_graph(cls, full_neighbors: jax.Array, r_max: int) -> "NeighborStore":
-        """Extract the first r_max columns (closest neighbors first)."""
+        """Extract the first r_max columns (the longest edges)."""
         r = full_neighbors.shape[1]
         return cls(neighbors=full_neighbors[:, : min(r_max, r)])
 

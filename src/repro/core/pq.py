@@ -28,6 +28,24 @@ import jax.numpy as jnp
 import numpy as np
 
 
+def _mm(a: jax.Array, b: jax.Array) -> jax.Array:
+    """f32 matmul at full precision.  The TPU's default precision rounds
+    f32 operands to bf16, and the ||x||^2 - 2 x.c + ||c||^2 expansion of
+    every distance below turns that rounding into cancellation errors as
+    large as the distances themselves (ADC ordering, hence recall,
+    collapses).  On the CPU this is the default anyway."""
+    return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
+
+
+def argmin_f32(x: jax.Array, axis: int) -> jax.Array:
+    """``jnp.argmin`` that compares the f32 values themselves.  The TPU
+    compiler lowers a plain argmin, whose minimum is discarded, to a
+    reduce over bf16 values, so near-ties (nearest centroids, nearest
+    prune candidates) resolve at 8 bits of mantissa.  The first index of
+    the f32 minimum is the same answer argmin gives everywhere else."""
+    return jnp.argmax(x == jnp.min(x, axis=axis, keepdims=True), axis=axis)
+
+
 class PQCodec(NamedTuple):
     """Trained PQ codebooks."""
 
@@ -40,6 +58,26 @@ class PQCodec(NamedTuple):
         return self.books.shape[0] * self.books.shape[2]
 
 
+# rows per block of the nearest-centroid search: the (rows, K) distance
+# block of every chunk stays small next to the corpus (32 chunks x 16k
+# rows x 256 centroids x 4 B = 512 MiB)
+_ASSIGN_ROWS = 16384
+
+
+def _nearest(sub: jax.Array, book: jax.Array) -> jax.Array:
+    """Nearest centroid of each row, by ||x||^2 - 2 x.c + ||c||^2.
+    sub (N, Dc), book (K, Dc) -> (N,) int32, in blocks of _ASSIGN_ROWS
+    rows (``argmin_f32`` reads its distances twice, so a whole (N, K)
+    block per chunk would be materialized)."""
+    book_sq = jnp.sum(book * book, axis=1)
+
+    def one(row):
+        d = jnp.sum(row * row) - 2.0 * _mm(book, row) + book_sq
+        return argmin_f32(d, axis=0).astype(jnp.int32)
+
+    return jax.lax.map(one, sub, batch_size=_ASSIGN_ROWS)
+
+
 def _kmeans_one_chunk(sub: jax.Array, k: int, iters: int, key: jax.Array) -> jax.Array:
     """Lloyd's k-means for one PQ chunk. sub: (N, Dc) -> (k, Dc)."""
     n = sub.shape[0]
@@ -47,16 +85,10 @@ def _kmeans_one_chunk(sub: jax.Array, k: int, iters: int, key: jax.Array) -> jax
     cents = sub[init_idx]
 
     def step(cents, _):
-        # (N, k) squared distances via ||x||^2 - 2 x.c + ||c||^2
-        d = (
-            jnp.sum(sub * sub, axis=1, keepdims=True)
-            - 2.0 * sub @ cents.T
-            + jnp.sum(cents * cents, axis=1)[None, :]
-        )
-        assign = jnp.argmin(d, axis=1)
+        assign = _nearest(sub, cents)
         one_hot = jax.nn.one_hot(assign, k, dtype=sub.dtype)  # (N, k)
         counts = one_hot.sum(axis=0)  # (k,)
-        sums = one_hot.T @ sub  # (k, Dc)
+        sums = _mm(one_hot.T, sub)  # (k, Dc)
         new = jnp.where(counts[:, None] > 0, sums / jnp.maximum(counts[:, None], 1.0), cents)
         return new, None
 
@@ -92,15 +124,7 @@ def encode_pq(codec: PQCodec, vectors: jax.Array) -> jax.Array:
     c, k, dc = codec.books.shape
     subs = vectors.reshape(n, c, dc)
 
-    def per_chunk(sub, book):  # sub (N, Dc), book (K, Dc)
-        d2 = (
-            jnp.sum(sub * sub, axis=1, keepdims=True)
-            - 2.0 * sub @ book.T
-            + jnp.sum(book * book, axis=1)[None, :]
-        )
-        return jnp.argmin(d2, axis=1).astype(jnp.int32)
-
-    codes = jax.vmap(per_chunk, in_axes=(1, 0), out_axes=1)(subs, codec.books)
+    codes = jax.vmap(_nearest, in_axes=(1, 0), out_axes=1)(subs, codec.books)
     return codes  # (N, C)
 
 
@@ -127,7 +151,7 @@ def build_lut(codec: PQCodec, queries: jax.Array) -> jax.Array:
     def per_chunk(qc, book):  # (B, Dc), (K, Dc)
         return (
             jnp.sum(qc * qc, axis=1, keepdims=True)
-            - 2.0 * qc @ book.T
+            - 2.0 * _mm(qc, book.T)
             + jnp.sum(book * book, axis=1)[None, :]
         )
 
@@ -143,7 +167,7 @@ def adc_lookup(lut: jax.Array, codes: jax.Array, *, use_kernel: bool = False) ->
     if use_kernel:
         from repro.kernels import ops as kops
 
-        return kops.pq_lookup(lut, codes)
+        return kops.pq_scan(lut, codes)
     return adc_lookup_ref(lut, codes)
 
 

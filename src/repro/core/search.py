@@ -58,6 +58,7 @@ from repro.core import pq as pqm
 from repro.core.filter_store import CheckFn
 from repro.core.neighbor_store import NeighborStore
 from repro.kernels import fused_traversal as ftk
+from repro.kernels import ref as kref
 from repro.store.cache import CachedMaskFn
 from repro.store.vector_store import RecordFetchFn
 
@@ -80,8 +81,8 @@ class SearchConfig:
     # run stage A (ADC + masks + beam select + frontier merge) as ONE
     # fused Pallas pass per round (kernels.fused_traversal) instead of
     # separate ops with HBM round-trips between them.  Bit-identical to
-    # the unfused loop at any mode/tier/depth; silently falls back when
-    # the shapes or backend don't support the kernel.
+    # the unfused loop at any mode/tier/depth; shapes the kernel cannot
+    # hold raise ValueError (kernels.fused_traversal.check_fused_supported).
     use_fused_kernel: bool = False
 
     def __post_init__(self):
@@ -113,20 +114,19 @@ class SearchOutput(NamedTuple):
 
 
 def _adc_ids(lut: jax.Array, codes: jax.Array, ids: jax.Array, use_kernel: bool) -> jax.Array:
-    """PQ distances for gathered ids. lut (B,C,K), codes (N,C), ids (B,M)."""
+    """PQ distances for gathered ids. lut (B,C,K), codes (N,C), ids (B,M).
+
+    The jnp path is ``kref.pq_lookup_gathered_ref``: a gather and a fixed
+    pairwise tree over chunks, the same arithmetic as the Pallas ADC
+    kernels and the fused round, so all three agree bit for bit.
+    """
     got = codes[jnp.maximum(ids, 0)]  # (B, M, C)
     if use_kernel:
         from repro.kernels import ops as kops
 
         d = kops.pq_lookup_gathered(lut, got)
     else:
-        # sum_c lut[b, c, got[b, m, c]]
-        b, m, c = got.shape
-        d = jnp.take_along_axis(
-            lut.transpose(0, 2, 1),  # (B, K, C)
-            got,  # (B, M, C) indexes K axis
-            axis=1,
-        ).sum(axis=-1)
+        d = kref.pq_lookup_gathered_ref(lut, got)
     # fence the reduction (same reason as _exact_dist): these distances
     # order the frontier, so an ULP of context-dependent fusion drift
     # would change traversal between the unfused and fused-kernel loops
@@ -137,10 +137,12 @@ def _adc_ids(lut: jax.Array, codes: jax.Array, ids: jax.Array, use_kernel: bool)
 def _exact_dist(queries: jax.Array, vecs: jax.Array, use_kernel: bool) -> jax.Array:
     """(B, D) queries vs (B, W, D) fetched rows -> (B, W) squared L2.
 
-    Fenced with optimization barriers: the sum reduction must produce the
-    same bits regardless of what XLA fuses around the call site, or the
-    sync / pipelined / fused-kernel loops (different graphs, same math)
-    could drift by an ULP in their exact result distances.
+    Fenced with optimization barriers and summed by the fixed pairwise
+    tree of ``kref.pairwise_sum`` rather than ``jnp.sum``: XLA's reduce
+    accumulation order is implementation-defined and can differ between
+    otherwise-identical modules, which showed up as 1-ULP drift between
+    the sync / pipelined / fused-kernel loops (different graphs, same
+    math).  Explicit adds are IEEE-strict.
     """
     queries, vecs = jax.lax.optimization_barrier((queries, vecs))
     if use_kernel:
@@ -148,19 +150,7 @@ def _exact_dist(queries: jax.Array, vecs: jax.Array, use_kernel: bool) -> jax.Ar
 
         return jax.lax.optimization_barrier(kops.l2_dist(queries, vecs))
     diff = vecs - queries[:, None, :]
-    sq = diff * diff
-    # Fixed-association pairwise tree instead of jnp.sum: XLA's reduce
-    # accumulation order is implementation-defined and can differ between
-    # otherwise-identical modules (the barrier fences fusion, not reduce
-    # codegen), which showed up as 1-ULP drift between the unfused and
-    # fused-kernel search loops.  Explicit adds are IEEE-strict.
-    while sq.shape[-1] > 1:
-        half = sq.shape[-1] // 2 * 2
-        head = sq[..., 0:half:2] + sq[..., 1:half:2]
-        if half != sq.shape[-1]:
-            head = jnp.concatenate([head, sq[..., half:]], axis=-1)
-        sq = head
-    return jax.lax.optimization_barrier(sq[..., 0])
+    return jax.lax.optimization_barrier(kref.pairwise_sum(diff * diff))
 
 
 @functools.partial(jax.jit, static_argnames=("config",))
@@ -329,19 +319,14 @@ def filtered_search(
     # AND selects the next beam — so the loop carries the kernel's output
     # (a FusedRound) instead of a bare frontier.  Results are bit-identical
     # (the kernel replicates the stable-sort semantics of frontier.insert /
-    # best_unexpanded exactly); fall back silently when the adjacency
-    # width can't be probed or the shapes/backend are unsupported.
+    # best_unexpanded exactly).  Shapes the kernel cannot hold raise.
     use_fused = config.use_fused_kernel
     if use_fused:
-        try:
-            probe = (lambda i: submit(i)[1]) if pipelined else (lambda i: fetch(i)[1])
-            nbrs_s = jax.eval_shape(probe, jax.ShapeDtypeStruct((b, W), jnp.int32))
-            m_new = W * (int(nbrs_s.shape[-1]) + r_max)
-            use_fused = ftk.fused_supported(
-                l=L, width=W, m=m_new, c=codes.shape[1], k=lut.shape[2]
-            )
-        except Exception:
-            use_fused = False
+        probe = (lambda i: submit(i)[1]) if pipelined else (lambda i: fetch(i)[1])
+        nbrs_s = jax.eval_shape(probe, jax.ShapeDtypeStruct((b, W), jnp.int32))
+        ftk.check_fused_supported(
+            l=L, width=W, m=W * (int(nbrs_s.shape[-1]) + r_max), k=lut.shape[2]
+        )
 
     # Trace-time dispatch accounting: this Python body runs once per jit
     # trace (shape/config change), not per call, so this counts *traces*
@@ -354,8 +339,8 @@ def filtered_search(
         pipelined="1" if pipelined else "0",
     ).inc()
 
-    if use_fused:  # gatelint: disable=trace-host-branch — trace-static: r_max is pytree aux (a Python int) and fused_supported returns a host bool
-        # Pallas kernel on TPU/GPU, its bit-identical jnp twin on CPU —
+    if use_fused:  # gatelint: disable=trace-host-branch — trace-static: a SearchConfig field
+        # Pallas kernel on TPU, its bit-identical jnp twin elsewhere —
         # see fused_round_for_backend for why interpret mode stays out of
         # the serving loop
         round_fn = ftk.fused_round_for_backend()

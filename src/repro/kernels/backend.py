@@ -1,15 +1,15 @@
 """Backend capability probe shared by every Pallas kernel wrapper.
 
-Compiled Pallas lowering exists for TPU (Mosaic) and GPU (Triton); on
-every other backend (CPU foremost) the kernels run in interpret mode —
-bit-accurate kernel-body semantics, evaluated as plain XLA ops.
+The kernels are written for the TPU's Mosaic lowering (lane rotations,
+(8, 128)-tiled blocks); on every other backend (CPU foremost) they run
+in interpret mode — bit-accurate kernel-body semantics, evaluated as
+plain XLA ops.
 
 ``interpret=None`` on a kernel entry point means "resolve from the
-backend": compiled whenever the backend supports it, interpret
-otherwise.  Passing an explicit bool is an opt-out in either direction
-(``interpret=True`` forces interpretation on TPU for debugging;
-``interpret=False`` on CPU will fail loudly rather than silently
-interpret).
+backend": compiled on a TPU, interpret elsewhere.  Passing an explicit
+bool is an opt-out in either direction (``interpret=True`` forces
+interpretation on TPU for debugging; ``interpret=False`` on CPU fails
+loudly rather than silently interpret).
 """
 from __future__ import annotations
 
@@ -17,7 +17,7 @@ import functools
 
 import jax
 
-_COMPILED_BACKENDS = ("tpu", "gpu")
+_COMPILED_BACKENDS = ("tpu",)
 
 
 @functools.cache

@@ -8,9 +8,8 @@ just I/O, bounds graph-ANNS throughput).  This kernel fuses one whole
 round into a single VMEM-resident pass per query:
 
   1. **ADC PQ-lookup** over the round's gathered candidate codes — the
-     same one-hot × LUT contraction as ``pq_lookup`` (MXU-friendly, and
-     bitwise-identical to the unfused ``take_along_axis(...).sum(-1)``
-     reference on every backend we pin).
+     same one-hot select-and-sum as ``pq_lookup`` (``adc_row``), bitwise
+     equal to the unfused gather + pairwise-tree reference.
   2. **Kill masking** — invalid ids and within-concat duplicates go to
      (+INF, -1), replicating ``frontier.insert``'s ``_dedup_mask``
      (earlier slot wins) exactly.
@@ -20,10 +19,9 @@ round into a single VMEM-resident pass per query:
      ascending sort bit-for-bit, so the merged frontier equals
      ``jnp.argsort``'s.  ``expanded`` / filter-pass flags ride along as
      payload lanes through every compare-exchange.
-  4. **Beam selection** — rank-by-pairwise-comparison over the merged
-     frontier picks the ``width`` best unexpanded entries (ties by slot,
-     matching ``frontier.best_unexpanded``'s stable argsort) and marks
-     them expanded.
+  4. **Beam selection** — the ``width`` best unexpanded entries of the
+     merged frontier (ties by slot, matching ``frontier.best_unexpanded``'s
+     stable argsort) are picked by a prefix count and marked expanded.
   5. **Filter / tunnel masks** — the per-mode fetch/tunnel/result/exact
      mask logic (``mode_masks`` below — the *same function* the unfused
      loop calls) runs on the selected beam inside the kernel.
@@ -38,9 +36,13 @@ previous round's candidates and selects the next beam, which is exactly
 carries the selection in loop state; results are bit-identical (pinned
 by the fused-vs-unfused parity lattice in ``tests/test_fused_traversal``).
 
-Everything is padded to powers of two with (+INF, -1, seq>=real) pad
-entries, which sort strictly after every real slot — M (candidate count)
-and L (frontier length) need not be powers of two.
+Layout: the wrapper lays each query's round out as one lane row of P
+slots, [frontier (L) | candidates (M) | pads], P a power of two of at
+least one lane tile.  Pads are (+INF, -1, seq>=real) entries, which sort
+strictly after every real slot, so M and L need not be powers of two.
+Every block's last two dimensions are whole array dimensions, as the
+TPU lowering requires; the sorting network and the prefix count move
+lanes with rotations, not gathers.
 """
 from __future__ import annotations
 
@@ -51,7 +53,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import pq_lookup as pqk
+from repro.kernels import topk_merge as tkm
 from repro.kernels.backend import resolve_interpret
 
 # numpy scalars, not jnp: the kernel body references them, and a traced
@@ -59,14 +64,12 @@ from repro.kernels.backend import resolve_interpret
 INF = np.float32(3.4e38)
 INVALID = np.int32(-1)
 
-# ADC one-hot workspace tile: bounds VMEM at C * _ADC_TILE * K * 4 bytes
-# (4 MB at C=32, K=256) regardless of the candidate count M.
-_ADC_TILE = 128
-
-# conservative ceilings for the silent fallback: the padded sort width
-# (VPU lanes per compare-exchange) and the one-hot workspace bytes
-_MAX_SORT = 4096
-_MAX_ADC_BYTES = 8 * 1024 * 1024
+# VMEM ceilings of one program: the (P, P) dedup mask over the padded
+# sort width P, and the (K, P) f32 one-hot ADC workspace
+_MAX_SORT = 1024
+_MAX_ADC_BYTES = 4 * 1024 * 1024
+# the TPU's lane width: the sort row is padded to at least this many lanes
+_LANES = 128
 
 
 def mode_masks(mode: str, sel_ids, valid, passes, entry_ids):
@@ -110,86 +113,59 @@ class FusedRound(NamedTuple):
     exact_mask: jax.Array  # bool
 
 
-def fused_supported(*, l: int, width: int, m: int, c: int, k: int,
-                    backend: str | None = None) -> bool:
-    """Can the fused kernel serve these shapes on this backend?
+def sort_width(l: int, m: int) -> int:
+    """Padded lane width P of the sort row: a power of two >= L + M and
+    >= one lane tile."""
+    return max(1 << (l + m - 1).bit_length(), _LANES)
 
-    Callers fall back to the unfused loop (bit-identical results, just
-    more HBM round-trips) when this returns False — the flag is a perf
-    knob, never a correctness one.
+
+def check_fused_supported(*, l: int, width: int, m: int, k: int) -> None:
+    """Raise ``ValueError`` naming the limit these shapes break.
+
+    The fused loop has no fallback: a caller that asks for it at shapes
+    one program cannot hold gets this error, not the unfused loop.
     """
-    backend = backend or jax.default_backend()
-    if backend not in ("cpu", "gpu", "tpu"):
-        return False
     if width < 1 or l < 1 or m < 0:
-        return False
-    total = l + m
-    pad = 1 << (total - 1).bit_length()
-    if pad > _MAX_SORT:
-        return False
-    if c * _ADC_TILE * k * 4 > _MAX_ADC_BYTES:
-        return False
-    return True
+        raise ValueError(
+            f"fused traversal needs width >= 1, L >= 1 and M >= 0 "
+            f"(got width={width}, L={l}, M={m})"
+        )
+    p = sort_width(l, m)
+    if p > _MAX_SORT:
+        raise ValueError(
+            f"fused traversal sort width {p} (L={l} + M={m}, padded) exceeds "
+            f"{_MAX_SORT}: its ({p}, {p}) dedup mask would not fit VMEM"
+        )
+    if k * p * 4 > _MAX_ADC_BYTES:
+        raise ValueError(
+            f"fused traversal one-hot ADC workspace K*P*4 = {k * p * 4} bytes "
+            f"(K={k}, P={p}) exceeds {_MAX_ADC_BYTES}"
+        )
 
 
-def _adc(lut, codes, ids):
-    """In-kernel ADC: dist[m] = Σ_c lut[c, codes[m, c]]; invalid -> +INF.
+def _bitonic_merge(dists, ids, exp, pas, lane):
+    """Stable ascending sort of a (1, P) row and its payload lanes.
 
-    Tiled over M so the one-hot workspace stays bounded; each tile is the
-    same batched-over-C contraction as ``pq_lookup._adc_kernel`` (whose
-    ``jnp.sum`` over chunks is bitwise-equal to the unfused
-    ``take_along_axis(...).sum(-1)`` — pinned in tests).
+    Pad lanes hold (+INF, -1, expanded, fail) and sit *after* every real
+    slot, so with the seq lane as tiebreak they sort strictly last among
+    INF ties, and the network's total order on (dist, seq) equals a
+    stable sort by distance.  Partners come from lane rotations
+    (``topk_merge.partner_lanes``).
     """
-    c, k = lut.shape
-    m = codes.shape[0]
-    parts = []
-    for t0 in range(0, m, _ADC_TILE):
-        tile = codes[t0 : min(t0 + _ADC_TILE, m)]  # (Mt, C)
-        iota_k = jax.lax.broadcasted_iota(jnp.int32, (c, tile.shape[0], k), 2)
-        onehot = (tile.T[:, :, None] == iota_k).astype(lut.dtype)  # (C, Mt, K)
-        per_chunk = jax.lax.dot_general(
-            onehot, lut,
-            dimension_numbers=(((2,), (1,)), ((0,), (0,))),  # batch C, contract K
-            preferred_element_type=jnp.float32,
-        )  # (C, Mt)
-        parts.append(jnp.sum(per_chunk, axis=0))
-    d = jnp.concatenate(parts) if len(parts) > 1 else parts[0]
-    return jnp.where(ids >= 0, d, INF)
-
-
-def _bitonic_merge(dists, ids, exp, pas, total: int):
-    """Stable ascending sort of (dists, payload) via a bitonic network.
-
-    ``total`` real entries are padded to a power of two with
-    (+INF, -1, expanded, fail) lanes whose seq numbers sit *after* every
-    real slot, so pads sort strictly last among INF ties.  The seq lane
-    makes the network's total order equal a stable sort by distance.
-    """
-    p = 1 << (total - 1).bit_length()
-    if p != total:
-        pad = p - total
-        dists = jnp.concatenate([dists, jnp.full((pad,), INF)])
-        ids = jnp.concatenate([ids, jnp.full((pad,), INVALID)])
-        exp = jnp.concatenate([exp, jnp.ones((pad,), exp.dtype)])
-        pas = jnp.concatenate([pas, jnp.zeros((pad,), pas.dtype)])
-    seq = jax.lax.iota(jnp.int32, p)
-    idx = jax.lax.iota(jnp.int32, p)
-    d, i, e, f, s = dists, ids, exp, pas, seq
-    logp = p.bit_length() - 1
-    for stage in range(logp):
+    p = dists.shape[-1]
+    d, i, e, f, s = dists, ids, exp, pas, lane
+    for stage in range(p.bit_length() - 1):
         block = 1 << (stage + 1)
+        ascending = (lane & block) == 0
         for sub in reversed(range(stage + 1)):
             j = 1 << sub
-            partner = idx ^ j
-            pd, pi, pe, pf, ps = d[partner], i[partner], e[partner], f[partner], s[partner]
+            lower = (lane & j) == 0
+            pd, pi, pe, pf, ps = (tkm.partner_lanes(x, j, lower)
+                                  for x in (d, i, e, f, s))
             # strict lexicographic (dist, seq) — seqs are unique, so this
             # is a total order and == / >= cases never arise
             lt = (d < pd) | ((d == pd) & (s < ps))
-            is_lower = (idx & j) == 0
-            ascending = (idx & block) == 0
-            keep = jnp.where(ascending,
-                             jnp.where(is_lower, lt, ~lt),
-                             jnp.where(is_lower, ~lt, lt))
+            keep = lt == (ascending == lower)
             d = jnp.where(keep, d, pd)
             i = jnp.where(keep, i, pi)
             e = jnp.where(keep, e, pe)
@@ -199,69 +175,71 @@ def _bitonic_merge(dists, ids, exp, pas, total: int):
 
 
 def _fused_kernel(
-    fid_ref, fd_ref, fexp_ref, fpass_ref,
-    nid_ref, ncodes_ref, npass_ref, lut_ref, entry_ref,
+    ids_ref, idc_ref, d_ref, exp_ref, pas_ref, codes_ref, lut_ref, entry_ref,
     ofid_ref, ofd_ref, ofexp_ref, ofpass_ref,
     osel_ref, ovalid_ref, ofids_ref, ofetch_ref, otun_ref, ores_ref, oexact_ref,
     *, mode: str, l: int, m: int, width: int,
 ):
     """One query's round: merge M candidates into the L-frontier, select
-    the next W-beam, emit its per-mode masks.  Bool lanes travel as i32."""
-    fid = fid_ref[0]
-    fd = fd_ref[0]
-    fexp = fexp_ref[0]
-    fpass = fpass_ref[0]
+    the next W-beam, emit its per-mode masks.
 
-    if m:
-        nid = nid_ref[0]
-        nd = _adc(lut_ref[0], ncodes_ref[0], nid)
-        ids = jnp.concatenate([fid, nid])
-        dists = jnp.concatenate([fd, nd])
-        exp = jnp.concatenate([fexp, jnp.zeros((m,), fexp.dtype)])
-        pas = jnp.concatenate([fpass, npass_ref[0]])
-    else:  # round-0 call: nothing to merge, just select from the frontier
-        ids, dists, exp, pas = fid, fd, fexp, fpass
+    Rows are (1, P) lane rows of [frontier | candidates | pads]; the ids
+    also come as a (P, 1) column for the pairwise dedup.  Frontier
+    outputs are rows (the wrapper keeps the first L lanes), beam outputs
+    (W, 1) columns.  Bool lanes travel as i32."""
+    ids = ids_ref[0]  # (1, P)
+    p = ids.shape[-1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, p), 1)
+    dists = d_ref[0]
+    exp = exp_ref[0]
+    pas = pas_ref[0]
+    if m:  # candidates get their ADC distance; round 0 has none
+        nd = pqk.adc_row(lut_ref[0], codes_ref[0])
+        dists = jnp.where((lane >= l) & (lane < l + m), nd, dists)
 
-    total = l + m
     # kill mask, exactly as frontier.insert: a slot dies if it duplicates
     # an EARLIER slot holding the same (non-negative) id, or its own id is
-    # invalid; dead slots become (+INF, -1)
-    pos = jax.lax.iota(jnp.int32, total)
-    earlier = pos[None, :] < pos[:, None]  # [a, b] — slot b precedes a
-    same = ids[None, :] == ids[:, None]
-    dup = jnp.any(same & earlier & (ids[None, :] >= 0), axis=-1)
+    # invalid; dead slots become (+INF, -1).  [a, b]: slot a precedes b.
+    col = idc_ref[0]  # (P, 1)
+    earlier = (jax.lax.broadcasted_iota(jnp.int32, (p, p), 0)
+               < jax.lax.broadcasted_iota(jnp.int32, (p, p), 1))
+    hit = (col == ids) & earlier & (col >= 0)
+    dup = jnp.max(hit.astype(jnp.int32), axis=0, keepdims=True) > 0
     dists = jnp.where(dup | (ids < 0), INF, dists)
     ids = jnp.where(dists >= INF, INVALID, ids)
 
-    sd, sids, sexp, spas = _bitonic_merge(dists, ids, exp, pas, total)
-    mf_d, mf_ids, mf_exp, mf_pas = sd[:l], sids[:l], sexp[:l], spas[:l]
+    d, i, e, f = _bitonic_merge(dists, ids, exp, pas, lane)
 
-    # beam selection == frontier.best_unexpanded: stable argsort of the
-    # masked key, realized as rank-by-pairwise-comparison (ties by slot)
-    selkey = jnp.where((mf_exp == 0) & (mf_ids >= 0), mf_d, INF)
-    lpos = jax.lax.iota(jnp.int32, l)
-    prec = (selkey[None, :] < selkey[:, None]) | (
-        (selkey[None, :] == selkey[:, None]) & (lpos[None, :] < lpos[:, None])
-    )
-    rank = jnp.sum(prec.astype(jnp.int32), axis=-1)  # (L,)
-    selected = (rank < width) & (selkey < INF)
-    mf_exp = mf_exp | selected.astype(mf_exp.dtype)
+    # beam selection == frontier.best_unexpanded (stable argsort of the
+    # masked key).  The merged frontier is sorted by distance, so the
+    # selectable slots are already in key order, ties by slot: a slot's
+    # rank is the count of selectable slots before it (a log-step scan).
+    selectable = (lane < l) & (e == 0) & (i >= 0)
+    cnt = selectable.astype(jnp.int32)
+    incl = cnt
+    shift = 1
+    while shift < l:
+        incl = incl + jnp.where(lane >= shift, pltpu.roll(incl, shift, 1), 0)
+        shift *= 2
+    rank = incl - cnt
+    selected = selectable & (rank < width)
+    e = e | selected.astype(e.dtype)
 
-    # scatter the selected slots into beam order (rank w -> lane w)
-    wpos = jax.lax.iota(jnp.int32, width)
-    oh = (rank[None, :] == wpos[:, None]) & selected[None, :]  # (W, L)
-    valid = jnp.any(oh, axis=-1)
-    sel_ids = jnp.sum(jnp.where(oh, mf_ids[None, :], 0), axis=-1)
+    # gather the selected slots into beam order (rank w -> row w)
+    oh = (rank == jax.lax.broadcasted_iota(jnp.int32, (width, p), 0)) & selected
+    valid = jnp.max(oh.astype(jnp.int32), axis=1, keepdims=True) > 0
+    sel_ids = jnp.sum(jnp.where(oh, i, 0), axis=1, keepdims=True)
     sel_ids = jnp.where(valid, sel_ids, INVALID)
-    passes = jnp.any(oh & (mf_pas[None, :] != 0), axis=-1) & valid
+    passes = (jnp.max(jnp.where(oh & (f != 0), 1, 0), axis=1, keepdims=True)
+              > 0) & valid
 
     fetch, tun, res, exact = mode_masks(mode, sel_ids, valid, passes,
-                                        entry_ref[0, 0])
+                                        entry_ref[0])
 
-    ofid_ref[0] = mf_ids
-    ofd_ref[0] = mf_d
-    ofexp_ref[0] = mf_exp
-    ofpass_ref[0] = mf_pas
+    ofid_ref[0] = i
+    ofd_ref[0] = d
+    ofexp_ref[0] = e
+    ofpass_ref[0] = f
     osel_ref[0] = sel_ids
     ovalid_ref[0] = valid.astype(jnp.int32)
     ofids_ref[0] = jnp.where(fetch, sel_ids, INVALID)
@@ -296,64 +274,59 @@ def fused_traversal_round(
     m = new_ids.shape[1]
     c, k = lut.shape[1], lut.shape[2]
     w = width
+    check_fused_supported(l=l, width=w, m=m, k=k)
+    p = sort_width(l, m)
 
-    kern = functools.partial(_fused_kernel, mode=mode, l=l, m=m, width=w)
-    row = lambda i: (i, 0)
-    row3 = lambda i: (i, 0, 0)
+    def lay(front, new, fill_new, fill_pad, dtype):
+        """[frontier | candidates | pads] as one (B, 1, P) row."""
+        parts = [front.astype(dtype)]
+        if new is not None:
+            parts.append(new.astype(dtype))
+        else:
+            parts.append(jnp.full((b, m), fill_new, dtype))
+        parts.append(jnp.full((b, p - l - m), fill_pad, dtype))
+        return jnp.concatenate(parts, axis=1)[:, None, :]
+
+    ids = lay(frontier_ids, new_ids, None, INVALID, jnp.int32)
+    codes_t = jnp.zeros((b, c, p), jnp.int32).at[:, :, l:l + m].set(
+        new_codes.astype(jnp.int32).transpose(0, 2, 1)
+    )
+    row = pl.BlockSpec((1, 1, p), lambda i: (i, 0, 0))
+    beam = pl.BlockSpec((1, w, 1), lambda i: (i, 0, 0))
     out = pl.pallas_call(
-        kern,
+        functools.partial(_fused_kernel, mode=mode, l=l, m=m, width=w),
         grid=(b,),
         in_specs=[
-            pl.BlockSpec((1, l), row),  # frontier ids
-            pl.BlockSpec((1, l), row),  # frontier dists
-            pl.BlockSpec((1, l), row),  # frontier expanded
-            pl.BlockSpec((1, l), row),  # frontier passes
-            pl.BlockSpec((1, max(m, 1)), row),  # new ids
-            pl.BlockSpec((1, max(m, 1), c), row3),  # new codes
-            pl.BlockSpec((1, max(m, 1)), row),  # new passes
-            pl.BlockSpec((1, c, k), row3),  # lut
-            pl.BlockSpec((1, 1), row),  # entry
+            row,  # ids
+            pl.BlockSpec((1, p, 1), lambda i: (i, 0, 0)),  # ids as a column
+            row,  # dists (candidate lanes are filled in by the kernel)
+            row,  # expanded
+            row,  # filter passes
+            pl.BlockSpec((1, c, p), lambda i: (i, 0, 0)),  # chunk-major codes
+            pl.BlockSpec((1, k, c), lambda i: (i, 0, 0)),  # transposed lut
+            pl.BlockSpec((1, 1, 1), lambda i: (i, 0, 0)),  # entry
         ],
-        out_specs=[
-            pl.BlockSpec((1, l), row),
-            pl.BlockSpec((1, l), row),
-            pl.BlockSpec((1, l), row),
-            pl.BlockSpec((1, l), row),
-            pl.BlockSpec((1, w), row),
-            pl.BlockSpec((1, w), row),
-            pl.BlockSpec((1, w), row),
-            pl.BlockSpec((1, w), row),
-            pl.BlockSpec((1, w), row),
-            pl.BlockSpec((1, w), row),
-            pl.BlockSpec((1, w), row),
-        ],
+        out_specs=[row] * 4 + [beam] * 7,
         out_shape=[
-            jax.ShapeDtypeStruct((b, l), jnp.int32),  # frontier ids
-            jax.ShapeDtypeStruct((b, l), jnp.float32),  # frontier dists
-            jax.ShapeDtypeStruct((b, l), jnp.int32),  # frontier expanded
-            jax.ShapeDtypeStruct((b, l), jnp.int32),  # frontier passes
-            jax.ShapeDtypeStruct((b, w), jnp.int32),  # sel_ids
-            jax.ShapeDtypeStruct((b, w), jnp.int32),  # valid
-            jax.ShapeDtypeStruct((b, w), jnp.int32),  # fetch_ids
-            jax.ShapeDtypeStruct((b, w), jnp.int32),  # fetch_mask
-            jax.ShapeDtypeStruct((b, w), jnp.int32),  # tunnel_mask
-            jax.ShapeDtypeStruct((b, w), jnp.int32),  # result_mask
-            jax.ShapeDtypeStruct((b, w), jnp.int32),  # exact_mask
-        ],
+            jax.ShapeDtypeStruct((b, 1, p), jnp.int32),  # frontier ids
+            jax.ShapeDtypeStruct((b, 1, p), jnp.float32),  # frontier dists
+            jax.ShapeDtypeStruct((b, 1, p), jnp.int32),  # frontier expanded
+            jax.ShapeDtypeStruct((b, 1, p), jnp.int32),  # frontier passes
+            # sel_ids, valid, fetch_ids, fetch/tunnel/result/exact masks
+        ] + [jax.ShapeDtypeStruct((b, w, 1), jnp.int32)] * 7,
         interpret=interpret,
     )(
-        frontier_ids.astype(jnp.int32),
-        frontier_dists.astype(jnp.float32),
-        frontier_expanded.astype(jnp.int32),
-        frontier_passes.astype(jnp.int32),
-        _at_least_one(new_ids.astype(jnp.int32), INVALID),
-        _at_least_one_3d(new_codes.astype(jnp.int32)),
-        _at_least_one(new_passes.astype(jnp.int32), jnp.int32(0)),
-        lut.astype(jnp.float32),
-        entry.astype(jnp.int32)[:, None],
+        ids,
+        ids.transpose(0, 2, 1),
+        lay(frontier_dists, None, 0.0, INF, jnp.float32),
+        lay(frontier_expanded, None, 0, 1, jnp.int32),
+        lay(frontier_passes, new_passes, None, 0, jnp.int32),
+        codes_t,
+        lut.astype(jnp.float32).transpose(0, 2, 1),
+        entry.astype(jnp.int32)[:, None, None],
     )
-    (ofid, ofd, ofexp, ofpass, osel, ovalid, ofids,
-     ofetch, otun, ores, oexact) = out
+    ofid, ofd, ofexp, ofpass = (x[:, 0, :l] for x in out[:4])
+    osel, ovalid, ofids, ofetch, otun, ores, oexact = (x[..., 0] for x in out[4:])
     return FusedRound(
         frontier_ids=ofid,
         frontier_dists=ofd,
@@ -372,7 +345,7 @@ def fused_traversal_round(
 def fused_round_for_backend():
     """The search loop's fused-round callable for this process's backend.
 
-    The Pallas kernel wherever a compiled lowering exists (TPU/GPU); its
+    The Pallas kernel wherever a compiled lowering exists (TPU); its
     bit-identical jnp twin (``ref.fused_traversal_round_ref``) elsewhere.
     Interpret-mode Pallas inside ``jax.lax.while_loop`` makes CPU XLA
     compile times pathological (minutes per mode, unbounded for some mask
@@ -388,17 +361,3 @@ def fused_round_for_backend():
     from repro.kernels import ref
 
     return ref.fused_traversal_round_ref
-
-
-def _at_least_one(x, fill):
-    """Pallas blocks need extent >= 1: widen an (B, 0) input to (B, 1)
-    dead lanes (the kernel's static ``m`` still reflects the real M)."""
-    if x.shape[1] == 0:
-        return jnp.full((x.shape[0], 1), fill, x.dtype)
-    return x
-
-
-def _at_least_one_3d(x):
-    if x.shape[1] == 0:
-        return jnp.zeros((x.shape[0], 1, x.shape[2]), x.dtype)
-    return x

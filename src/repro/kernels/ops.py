@@ -1,11 +1,10 @@
 """Jitted public wrappers over the Pallas kernels.
 
 ``interpret`` mode is selected automatically (``kernels.backend``):
-compiled Pallas wherever a compiled lowering exists (TPU via Mosaic, GPU
-via Triton), Python interpretation — bit-accurate kernel-body semantics —
-only where it doesn't (CPU).  Interpret mode is an explicit opt-out via
-the ``interpret=`` kwarg on the underlying modules, never a silent
-default on an accelerator.
+compiled Pallas on a TPU (Mosaic), Python interpretation — bit-accurate
+kernel-body semantics — on every other backend (CPU).  Interpret mode is
+an explicit opt-out via the ``interpret=`` kwarg on the underlying
+modules, never a silent default on a TPU.
 """
 from __future__ import annotations
 
@@ -23,10 +22,6 @@ def _interpret() -> bool:
 
 def pq_lookup_gathered(lut, codes, *, block_m: int = 128):
     return _pq.pq_lookup_gathered(lut, codes, block_m=block_m, interpret=_interpret())
-
-
-# Alias used by core.search
-pq_lookup = pq_lookup_gathered
 
 
 def pq_scan(lut, codes, *, block_n: int = 512):

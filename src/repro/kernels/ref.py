@@ -8,14 +8,33 @@ _INF = jnp.float32(3.4e38)
 _INVALID = jnp.int32(-1)
 
 
+def pairwise_sum(x: jax.Array) -> jax.Array:
+    """Sum over the last axis as a fixed pairwise tree: (0+1), (2+3), ...,
+    an odd tail carried to the end of the next level.
+
+    Every step is an elementwise add, whose IEEE result no compiler may
+    change, so the bits do not depend on what the backend fuses around
+    the call or how it would order a ``jnp.sum``.  The Pallas ADC kernels
+    sum their chunk partials in the same tree (``pq_lookup.tree_sum``),
+    which is what keeps them bitwise equal to this reference.
+    """
+    while x.shape[-1] > 1:
+        half = x.shape[-1] // 2 * 2
+        head = x[..., 0:half:2] + x[..., 1:half:2]
+        if half != x.shape[-1]:
+            head = jnp.concatenate([head, x[..., half:]], axis=-1)
+        x = head
+    return x[..., 0]
+
+
 def pq_lookup_gathered_ref(lut: jax.Array, codes: jax.Array) -> jax.Array:
     """lut (B, C, K) f32, codes (B, M, C) i32 -> (B, M) f32."""
     # out[b, m] = sum_c lut[b, c, codes[b, m, c]]
-    return jnp.take_along_axis(
-        lut.transpose(0, 2, 1),  # (B, K, C)
+    return pairwise_sum(jnp.take_along_axis(
+        lut.astype(jnp.float32).transpose(0, 2, 1),  # (B, K, C)
         codes,  # (B, M, C) indexes the K axis
         axis=1,
-    ).sum(axis=-1).astype(jnp.float32)
+    ))
 
 
 def pq_scan_ref(lut: jax.Array, codes: jax.Array) -> jax.Array:

@@ -138,8 +138,10 @@ class ShardedRecordStore:
 class HostOffloadRecordStore:
     """Records resident in host memory (``pinned_host``); fetch = host DMA.
 
-    Falls back to an in-memory store if the backend lacks host memory
-    spaces (e.g. some CPU builds).
+    On an accelerator the records must land in ``pinned_host``: a backend
+    that refuses it raises.  Only on the CPU backend, where device memory
+    already is host memory and the build may offer no ``pinned_host``
+    space, do the records stay in plain device memory.
     """
 
     vectors: jax.Array
@@ -147,15 +149,16 @@ class HostOffloadRecordStore:
 
     @classmethod
     def create(cls, vectors, neighbors) -> "HostOffloadRecordStore":
-        try:
-            dev = jax.devices()[0]
-            host_sharding = jax.sharding.SingleDeviceSharding(dev, memory_kind="pinned_host")
-            vectors = jax.device_put(jnp.asarray(vectors), host_sharding)
-            neighbors = jax.device_put(jnp.asarray(neighbors), host_sharding)
-        except (ValueError, RuntimeError):  # backend without pinned_host
-            vectors = jnp.asarray(vectors)
-            neighbors = jnp.asarray(neighbors)
-        return cls(vectors=vectors, neighbors=neighbors)
+        dev = jax.devices()[0]
+        if dev.platform == "cpu" and "pinned_host" not in {
+            m.kind for m in dev.addressable_memories()
+        }:
+            return cls(vectors=jnp.asarray(vectors), neighbors=jnp.asarray(neighbors))
+        host = jax.sharding.SingleDeviceSharding(dev, memory_kind="pinned_host")
+        return cls(
+            vectors=jax.device_put(jnp.asarray(vectors), host),
+            neighbors=jax.device_put(jnp.asarray(neighbors), host),
+        )
 
     def fetch_fn(self) -> RecordFetchFn:
         return Partial(_inmem_fetch, self.vectors, self.neighbors)

@@ -25,7 +25,6 @@ def _run(code: str, n_dev: int = 8):
 def test_sharded_record_store_matches_inmemory():
     _run("""
     import jax, jax.numpy as jnp, numpy as np
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.store.vector_store import ShardedRecordStore, InMemoryRecordStore
 
@@ -45,9 +44,9 @@ def test_sharded_record_store_matches_inmemory():
                                rows_per_shard=rows)
         return s.fetch_fn()(ids)
 
-    mapped = shard_map(run, mesh=mesh,
+    mapped = jax.shard_map(run, mesh=mesh,
         in_specs=(P("model", None), P("model", None), P(None, None)),
-        out_specs=(P(None, None, None), P(None, None, None)), check_rep=False)
+        out_specs=(P(None, None, None), P(None, None, None)), check_vma=False)
     got_v, got_n = jax.jit(mapped)(jnp.asarray(v_p), jnp.asarray(g_p), jnp.asarray(ids))
     ref = InMemoryRecordStore(vectors=jnp.asarray(vecs), neighbors=jnp.asarray(nbrs))
     want_v, want_n = ref.fetch_fn()(jnp.asarray(ids))

@@ -11,7 +11,8 @@ Contract under test (kernels/fused_traversal.py + core/search.py):
     output (ids, dists, every stat) to the unfused loop in all five modes,
     both cache tiers, and every pipeline depth — the flag is a perf knob,
     never a correctness one.
-  * ``fused_supported`` gates the silent fallback on shape/backend limits.
+  * ``check_fused_supported`` refuses shapes one program cannot hold
+    with an error naming the limit — there is no silent fallback.
 
 Interpret-mode Pallas builds are expensive on CPU, so tier-1 keeps one
 mode per lattice axis on a micro index; the full sweep is slow-marked.
@@ -104,22 +105,35 @@ def test_kernel_matches_twin_all_modes(mode, case):
                    all_filtered=(case == "all_filtered"))
 
 
-def test_fused_supported_limits():
-    """The silent-fallback predicate: shape/VMEM ceilings and backends."""
-    ok = dict(l=16, width=2, m=24, c=4, k=256)
-    assert ft.fused_supported(**ok)
-    assert not ft.fused_supported(**{**ok, "l": 4000, "m": 200})  # sort pad
-    assert not ft.fused_supported(**{**ok, "c": 64, "k": 1024})  # ADC bytes
-    assert not ft.fused_supported(**{**ok, "width": 0})
-    assert not ft.fused_supported(**{**ok, "m": -1})
-    assert not ft.fused_supported(**ok, backend="weird")
-    assert ft.fused_supported(**ok, backend="tpu")
+def test_fused_supported_limits(micro_engine, micro_corpus):
+    """Shapes past a VMEM ceiling raise an error that names the limit;
+    the search loop with use_fused_kernel=True raises it too instead of
+    running the unfused loop."""
+    ok = dict(l=16, width=2, m=24, k=256)
+    ft.check_fused_supported(**ok)
+    ft.check_fused_supported(l=64, width=8, m=8 * (32 + 16), k=256)  # smoke
+    with pytest.raises(ValueError, match="sort width"):
+        ft.check_fused_supported(**{**ok, "l": 4000, "m": 200})
+    with pytest.raises(ValueError, match="ADC workspace"):
+        ft.check_fused_supported(**{**ok, "k": 16384})
+    with pytest.raises(ValueError, match="width >= 1"):
+        ft.check_fused_supported(**{**ok, "width": 0})
+    with pytest.raises(ValueError, match="M >= 0"):
+        ft.check_fused_supported(**{**ok, "m": -1})
+    assert ft.sort_width(8, 8) == 128  # one lane tile at least
+    assert ft.sort_width(64, 384) == 512
+    _, _, queries = micro_corpus
+    with pytest.raises(ValueError, match="sort width"):
+        micro_engine.search(queries, search_config=SearchConfig(
+            mode="unfiltered", search_l=2000, beam_width=2,
+            use_fused_kernel=True))
 
 
 def test_interpret_resolution():
-    """interpret=None resolves from the backend; explicit bools win."""
+    """interpret=None resolves from the backend (compiled on TPU only);
+    explicit bools win."""
     assert supports_compiled_pallas("tpu")
-    assert supports_compiled_pallas("gpu")
+    assert not supports_compiled_pallas("gpu")
     assert not supports_compiled_pallas("cpu")
     assert resolve_interpret(None) == (not supports_compiled_pallas())
     assert resolve_interpret(True) is True

@@ -32,6 +32,18 @@ def test_graph_shape_and_padding(small_graph):
     assert not (nbrs[valid] == np.broadcast_to(rows, nbrs.shape)[valid]).any()
 
 
+def test_rows_longest_edge_first(small_graph):
+    """A row's prefix is the neighbor store: its longest edges come first,
+    -1 padding last."""
+    corpus, g = small_graph
+    nbrs = np.asarray(g.neighbors)
+    valid = nbrs >= 0
+    d = ((corpus[np.maximum(nbrs, 0)] - corpus[:, None, :]) ** 2).sum(-1)
+    d = np.where(valid, d, -1.0)
+    assert (np.diff(d, axis=1) <= 0).all()
+    assert (np.diff(valid.astype(np.int8), axis=1) <= 0).all()
+
+
 def test_medoid_is_most_central(small_graph):
     corpus, g = small_graph
     med = int(g.medoid)

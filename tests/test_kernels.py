@@ -79,13 +79,13 @@ def test_topk_merge_duplicate_distances_deterministic():
 
 
 def test_interpret_mode_resolution():
-    """Kernel wrappers run compiled wherever a lowering exists; interpret
-    is the resolved fallback (CPU), never a silent default elsewhere."""
+    """Kernel wrappers run compiled on a TPU; interpret is what every
+    other backend resolves to, never a silent default on a TPU."""
     from repro.kernels.backend import resolve_interpret, supports_compiled_pallas
 
     assert ops._interpret() == (not supports_compiled_pallas())
     assert resolve_interpret(None) == ops._interpret()
-    assert supports_compiled_pallas("tpu") and supports_compiled_pallas("gpu")
+    assert supports_compiled_pallas("tpu") and not supports_compiled_pallas("gpu")
     assert not supports_compiled_pallas("cpu")
     assert resolve_interpret(False) is False  # explicit opt-out wins
 

@@ -265,3 +265,31 @@ def test_multilabel_subset_search(tiny_corpus):
     for row, qt in zip(ids, qtags):
         for i in row[row >= 0]:
             assert set(qt) <= set(tags[int(i)])
+
+
+@pytest.mark.parametrize("env_dir", [False, True])
+def test_compile_cache_directory(monkeypatch, tmp_path, env_dir):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing else is set in code;
+    unset, the cache lives at the one fixed, git-ignored in-checkout path."""
+    import os
+
+    import jax
+
+    from repro import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        if env_dir:
+            monkeypatch.setenv(compile_cache.ENV_VAR, str(tmp_path))
+            assert compile_cache.configure_compile_cache() == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == before  # untouched
+        else:
+            monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+            got = compile_cache.configure_compile_cache()
+            repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+            assert got == os.path.join(repo, ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+            with open(os.path.join(repo, ".gitignore")) as f:
+                assert ".jax_cache/" in f.read().split()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
