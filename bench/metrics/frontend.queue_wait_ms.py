@@ -1,0 +1,7 @@
+"""frontend.queue_wait_ms: mean time a request of the window waited in
+the frontend's queue before a batch took it (``RequestTrace.queue_wait``)."""
+
+
+def read(run):
+    waits = [r.trace.queue_wait for r in run.requests if r.ok]
+    return 1e3 * sum(waits) / len(waits) if waits else None
