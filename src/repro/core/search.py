@@ -42,6 +42,21 @@ it), retirement preserves insertion order, and the drained vectors are
 byte-identical to the synchronous read, so output is **bit-identical**
 to the synchronous loop at every depth; ``pipeline_depth=1`` (the
 default) *is* the synchronous loop.  Only wall-clock changes.
+
+**Stage names.**  The loop's pieces run under ``jax.named_scope``
+names, which reach the compiled ops' ``op_name`` metadata and so the
+device ops of a profiler trace; they change no computation:
+
+  * ``select``  — beam selection (best unexpanded), the filter check,
+                  the mode masks, the stats, the loop condition;
+  * ``adc``     — PQ distances of the new candidates and of the entry;
+  * ``visited`` — the visited bitmap's test and update;
+  * ``merge``   — the frontier insert;
+  * ``fetch``   — record fetch / submit / drain, the cache split and
+                  visit counts, the tunnel path's neighbor lookup, the
+                  pipeline's rings;
+  * ``rerank``  — exact distances into the result heap (``retire``);
+  * ``fused_round`` — the fused stage-A kernel call, where it runs.
 """
 from __future__ import annotations
 
@@ -120,18 +135,19 @@ def _adc_ids(lut: jax.Array, codes: jax.Array, ids: jax.Array, use_kernel: bool)
     pairwise tree over chunks, the same arithmetic as the Pallas ADC
     kernels and the fused round, so all three agree bit for bit.
     """
-    got = codes[jnp.maximum(ids, 0)]  # (B, M, C)
-    if use_kernel:
-        from repro.kernels import ops as kops
+    with jax.named_scope("adc"):
+        got = codes[jnp.maximum(ids, 0)]  # (B, M, C)
+        if use_kernel:
+            from repro.kernels import ops as kops
 
-        d = kops.pq_lookup_gathered(lut, got)
-    else:
-        d = kref.pq_lookup_gathered_ref(lut, got)
-    # fence the reduction (same reason as _exact_dist): these distances
-    # order the frontier, so an ULP of context-dependent fusion drift
-    # would change traversal between the unfused and fused-kernel loops
-    d = jax.lax.optimization_barrier(d)
-    return jnp.where(ids >= 0, d, fr.INF)
+            d = kops.pq_lookup_gathered(lut, got)
+        else:
+            d = kref.pq_lookup_gathered_ref(lut, got)
+        # fence the reduction (same reason as _exact_dist): these distances
+        # order the frontier, so an ULP of context-dependent fusion drift
+        # would change traversal between the unfused and fused-kernel loops
+        d = jax.lax.optimization_barrier(d)
+        return jnp.where(ids >= 0, d, fr.INF)
 
 
 def _exact_dist(queries: jax.Array, vecs: jax.Array, use_kernel: bool) -> jax.Array:
@@ -207,7 +223,8 @@ def filtered_search(
         bit = jnp.uint32(1) << (idx % 32).astype(jnp.uint32)
         return (jnp.take_along_axis(vis, word, axis=1) & bit) != 0
 
-    visited = set_visited(visited, entry[:, None])
+    with jax.named_scope("visited"):
+        visited = set_visited(visited, entry[:, None])
 
     stats0 = SearchStats(
         n_ios=jnp.zeros((b,), jnp.int32),
@@ -229,57 +246,63 @@ def filtered_search(
         except touching the record itself.  Shared verbatim by the
         synchronous and pipelined loops, so their traversal (and stats)
         cannot diverge."""
-        sel_ids, slots, valid = fr.best_unexpanded(frontier, W)
-        frontier = fr.mark_expanded(frontier, slots, valid)
+        with jax.named_scope("select"):
+            sel_ids, slots, valid = fr.best_unexpanded(frontier, W)
+            frontier = fr.mark_expanded(frontier, slots, valid)
 
-        passes = filter_check(sel_ids) & valid  # in-memory predicate (filter store)
+            passes = filter_check(sel_ids) & valid  # in-memory predicate (filter store)
 
-        # per-mode dispatch masks — shared with the fused kernel body and
-        # its reference twin, so the three paths cannot drift
-        fetch_mask, tunnel_mask, result_mask, exact_mask = ftk.mode_masks(
-            mode, sel_ids, valid, passes, entry[:, None]
-        )
-
-        # ---- split fetches into cache hits and slow-tier reads
-        if cached_mask is None:
-            hit_mask = jnp.zeros_like(fetch_mask)
-        else:
-            hit_mask = cached_mask(sel_ids) & fetch_mask
-        slow_mask = fetch_mask & (~hit_mask)
-
-        if track_visits:
-            vc = vc.at[jnp.maximum(sel_ids, 0).ravel()].add(
-                jnp.where(fetch_mask, 1.0, 0.0).ravel()
+            # per-mode dispatch masks — shared with the fused kernel body and
+            # its reference twin, so the three paths cannot drift
+            fetch_mask, tunnel_mask, result_mask, exact_mask = ftk.mode_masks(
+                mode, sel_ids, valid, passes, entry[:, None]
             )
 
-        fetch_ids = jnp.where(fetch_mask, sel_ids, fr.INVALID)
-        stats = SearchStats(
-            n_ios=stats.n_ios + jnp.sum(slow_mask, axis=1).astype(jnp.int32),
-            n_tunnels=stats.n_tunnels + jnp.sum(tunnel_mask, axis=1).astype(jnp.int32),
-            n_exact=stats.n_exact + jnp.sum(exact_mask, axis=1).astype(jnp.int32),
-            n_hops=stats.n_hops + 1,
-            n_cache_hits=stats.n_cache_hits + jnp.sum(hit_mask, axis=1).astype(jnp.int32),
-            n_degraded=stats.n_degraded,  # advanced by retire, not stage A
-        )
+        # ---- split fetches into cache hits and slow-tier reads
+        with jax.named_scope("fetch"):
+            if cached_mask is None:
+                hit_mask = jnp.zeros_like(fetch_mask)
+            else:
+                hit_mask = cached_mask(sel_ids) & fetch_mask
+            slow_mask = fetch_mask & (~hit_mask)
+
+            if track_visits:
+                vc = vc.at[jnp.maximum(sel_ids, 0).ravel()].add(
+                    jnp.where(fetch_mask, 1.0, 0.0).ravel()
+                )
+
+            fetch_ids = jnp.where(fetch_mask, sel_ids, fr.INVALID)
+        with jax.named_scope("select"):
+            stats = SearchStats(
+                n_ios=stats.n_ios + jnp.sum(slow_mask, axis=1).astype(jnp.int32),
+                n_tunnels=stats.n_tunnels + jnp.sum(tunnel_mask, axis=1).astype(jnp.int32),
+                n_exact=stats.n_exact + jnp.sum(exact_mask, axis=1).astype(jnp.int32),
+                n_hops=stats.n_hops + 1,
+                n_cache_hits=stats.n_cache_hits + jnp.sum(hit_mask, axis=1).astype(jnp.int32),
+                n_degraded=stats.n_degraded,  # advanced by retire, not stage A
+            )
         return frontier, stats, vc, sel_ids, fetch_ids, tunnel_mask, result_mask
 
     def expand(frontier, visited, sel_ids, tunnel_mask, disk_nbrs):
         """Frontier growth from this round's neighbor lists (fetch path:
         full-R disk adjacency; tunnel path: the in-memory r_max slice)."""
-        if mode == "gate":
-            tun_ids = jnp.where(tunnel_mask, sel_ids, fr.INVALID)
-            tun_nbrs = neighbor_store.lookup(tun_ids)  # (B, W, R_max)
-        else:
-            tun_nbrs = jnp.full((b, W, r_max), fr.INVALID)
+        with jax.named_scope("fetch"):
+            if mode == "gate":
+                tun_ids = jnp.where(tunnel_mask, sel_ids, fr.INVALID)
+                tun_nbrs = neighbor_store.lookup(tun_ids)  # (B, W, R_max)
+            else:
+                tun_nbrs = jnp.full((b, W, r_max), fr.INVALID)
 
-        new = jnp.concatenate(
-            [disk_nbrs.reshape(b, -1), tun_nbrs.reshape(b, -1)], axis=-1
-        )
-        fresh = (new >= 0) & (~is_visited(visited, jnp.maximum(new, 0)))
-        new = jnp.where(fresh, new, fr.INVALID)
-        visited = set_visited(visited, new)
+        with jax.named_scope("visited"):
+            new = jnp.concatenate(
+                [disk_nbrs.reshape(b, -1), tun_nbrs.reshape(b, -1)], axis=-1
+            )
+            fresh = (new >= 0) & (~is_visited(visited, jnp.maximum(new, 0)))
+            new = jnp.where(fresh, new, fr.INVALID)
+            visited = set_visited(visited, new)
         new_d = _adc_ids(lut, codes, new, config.use_kernel)  # PQ priority signal
-        return fr.insert(frontier, new, new_d), visited
+        with jax.named_scope("merge"):
+            return fr.insert(frontier, new, new_d), visited
 
     def retire(results, stats, sel_ids, result_mask, vecs, live):
         """Stage B: score one round's fetched records and push them into
@@ -295,18 +318,20 @@ def filtered_search(
         vectors are finite, so with zero injected faults the sentinel
         never appears and this is bit-identical to the pre-resilience
         loop."""
-        exact_d = _exact_dist(queries, vecs, config.use_kernel)
-        deg = jnp.any(jnp.isinf(vecs), axis=-1) & result_mask & live
-        ok = result_mask & live & ~deg
-        exact_d = jnp.where(ok, exact_d, fr.INF)
-        results = fr.results_insert(
-            results, jnp.where(ok, sel_ids, fr.INVALID), exact_d
-        )
-        stats = stats._replace(
-            n_degraded=stats.n_degraded + jnp.sum(deg, axis=1).astype(jnp.int32)
-        )
-        return results, stats
+        with jax.named_scope("rerank"):
+            exact_d = _exact_dist(queries, vecs, config.use_kernel)
+            deg = jnp.any(jnp.isinf(vecs), axis=-1) & result_mask & live
+            ok = result_mask & live & ~deg
+            exact_d = jnp.where(ok, exact_d, fr.INF)
+            results = fr.results_insert(
+                results, jnp.where(ok, sel_ids, fr.INVALID), exact_d
+            )
+            stats = stats._replace(
+                n_degraded=stats.n_degraded + jnp.sum(deg, axis=1).astype(jnp.int32)
+            )
+            return results, stats
 
+    @jax.named_scope("select")
     def cond(state):
         frontier, _, _, stats = state[0], state[1], state[2], state[3]
         return jnp.any(fr.has_unexpanded(frontier)) & jnp.all(stats.n_hops < config.max_hops)
@@ -345,6 +370,7 @@ def filtered_search(
         # the serving loop
         round_fn = ftk.fused_round_for_backend()
 
+        @jax.named_scope("fused_round")
         def fused_call(fids, fds, fexp, fpass, new_ids, new_codes, new_passes):
             return round_fn(
                 fids, fds, fexp, fpass, new_ids, new_codes, new_passes,
@@ -354,47 +380,54 @@ def filtered_search(
         def fused_account(rnd, stats, vc):
             """The non-kernel half of stage A: cache-tier split, visit
             counters, stats — same arithmetic as the unfused stage_a."""
-            if cached_mask is None:
-                hit_mask = jnp.zeros_like(rnd.fetch_mask)
-            else:
-                hit_mask = cached_mask(rnd.sel_ids) & rnd.fetch_mask
-            slow_mask = rnd.fetch_mask & (~hit_mask)
-            if track_visits:
-                vc = vc.at[jnp.maximum(rnd.sel_ids, 0).ravel()].add(
-                    jnp.where(rnd.fetch_mask, 1.0, 0.0).ravel()
+            with jax.named_scope("fetch"):
+                if cached_mask is None:
+                    hit_mask = jnp.zeros_like(rnd.fetch_mask)
+                else:
+                    hit_mask = cached_mask(rnd.sel_ids) & rnd.fetch_mask
+                slow_mask = rnd.fetch_mask & (~hit_mask)
+                if track_visits:
+                    vc = vc.at[jnp.maximum(rnd.sel_ids, 0).ravel()].add(
+                        jnp.where(rnd.fetch_mask, 1.0, 0.0).ravel()
+                    )
+            with jax.named_scope("select"):
+                stats = SearchStats(
+                    n_ios=stats.n_ios + jnp.sum(slow_mask, axis=1).astype(jnp.int32),
+                    n_tunnels=stats.n_tunnels
+                    + jnp.sum(rnd.tunnel_mask, axis=1).astype(jnp.int32),
+                    n_exact=stats.n_exact
+                    + jnp.sum(rnd.exact_mask, axis=1).astype(jnp.int32),
+                    n_hops=stats.n_hops + 1,
+                    n_cache_hits=stats.n_cache_hits
+                    + jnp.sum(hit_mask, axis=1).astype(jnp.int32),
+                    n_degraded=stats.n_degraded,  # advanced by retire
                 )
-            stats = SearchStats(
-                n_ios=stats.n_ios + jnp.sum(slow_mask, axis=1).astype(jnp.int32),
-                n_tunnels=stats.n_tunnels
-                + jnp.sum(rnd.tunnel_mask, axis=1).astype(jnp.int32),
-                n_exact=stats.n_exact
-                + jnp.sum(rnd.exact_mask, axis=1).astype(jnp.int32),
-                n_hops=stats.n_hops + 1,
-                n_cache_hits=stats.n_cache_hits
-                + jnp.sum(hit_mask, axis=1).astype(jnp.int32),
-                n_degraded=stats.n_degraded,  # advanced by retire
-            )
             return stats, vc
 
         def fused_new(sel_ids, tunnel_mask, visited, disk_nbrs):
             """This round's candidate batch for the next kernel call —
             identical to the head of the unfused ``expand``, plus the code
             gather and filter verdicts the kernel consumes as payload."""
-            if mode == "gate":
-                tun_ids = jnp.where(tunnel_mask, sel_ids, fr.INVALID)
-                tun_nbrs = neighbor_store.lookup(tun_ids)  # (B, W, R_max)
-            else:
-                tun_nbrs = jnp.full((b, W, r_max), fr.INVALID)
-            new = jnp.concatenate(
-                [disk_nbrs.reshape(b, -1), tun_nbrs.reshape(b, -1)], axis=-1
-            )
-            fresh = (new >= 0) & (~is_visited(visited, jnp.maximum(new, 0)))
-            new = jnp.where(fresh, new, fr.INVALID)
-            visited = set_visited(visited, new)
-            new_codes = codes[jnp.maximum(new, 0)]
-            new_passes = filter_check(new)
+            with jax.named_scope("fetch"):
+                if mode == "gate":
+                    tun_ids = jnp.where(tunnel_mask, sel_ids, fr.INVALID)
+                    tun_nbrs = neighbor_store.lookup(tun_ids)  # (B, W, R_max)
+                else:
+                    tun_nbrs = jnp.full((b, W, r_max), fr.INVALID)
+            with jax.named_scope("visited"):
+                new = jnp.concatenate(
+                    [disk_nbrs.reshape(b, -1), tun_nbrs.reshape(b, -1)], axis=-1
+                )
+                fresh = (new >= 0) & (~is_visited(visited, jnp.maximum(new, 0)))
+                new = jnp.where(fresh, new, fr.INVALID)
+                visited = set_visited(visited, new)
+            with jax.named_scope("adc"):
+                new_codes = codes[jnp.maximum(new, 0)]
+            with jax.named_scope("select"):
+                new_passes = filter_check(new)
             return new, new_codes, new_passes, visited
 
+        @jax.named_scope("select")
         def fused_cond(state):
             rnd, stats = state[0], state[3]
             return jnp.any(rnd.valid) & jnp.all(stats.n_hops < config.max_hops)
@@ -402,9 +435,11 @@ def filtered_search(
         # pre-loop call (M=0): select round 0's beam from the entry-seeded
         # frontier.  any(valid) ≡ has_unexpanded, so the loop condition is
         # unchanged in substance.
+        with jax.named_scope("select"):
+            passes0 = filter_check(frontier.ids)
         rnd0 = fused_call(
             frontier.ids, frontier.dists, frontier.expanded,
-            filter_check(frontier.ids),
+            passes0,
             jnp.zeros((b, 0), jnp.int32),
             jnp.zeros((b, 0, codes.shape[1]), jnp.int32),
             jnp.zeros((b, 0), bool),
@@ -414,7 +449,8 @@ def filtered_search(
             def fused_body(state):
                 rnd, results, visited, stats, vc = state
                 stats, vc = fused_account(rnd, stats, vc)
-                vecs, disk_nbrs = fetch(rnd.fetch_ids)
+                with jax.named_scope("fetch"):
+                    vecs, disk_nbrs = fetch(rnd.fetch_ids)
                 results, stats = retire(
                     results, stats, rnd.sel_ids, rnd.result_mask, vecs,
                     jnp.bool_(True),
@@ -453,7 +489,8 @@ def filtered_search(
              p_ids, p_fids, p_rm, p_tok) = state
             r = stats.n_hops[0]
             stats, vc = fused_account(rnd, stats, vc)
-            token, disk_nbrs = submit(rnd.fetch_ids)
+            with jax.named_scope("fetch"):
+                token, disk_nbrs = submit(rnd.fetch_ids)
             new, new_codes, new_passes, visited = fused_new(
                 rnd.sel_ids, rnd.tunnel_mask, visited, disk_nbrs
             )
@@ -461,14 +498,15 @@ def filtered_search(
                 rnd.frontier_ids, rnd.frontier_dists, rnd.frontier_expanded,
                 rnd.frontier_passes, new, new_codes, new_passes,
             )
-            wp = jnp.mod(r, depth)
-            p_ids = p_ids.at[wp].set(rnd.sel_ids)
-            p_fids = p_fids.at[wp].set(rnd.fetch_ids)
-            p_rm = p_rm.at[wp].set(rnd.result_mask)
-            p_tok = p_tok.at[wp].set(token)
-            live = r >= depth - 1
-            dp = jnp.mod(r - (depth - 1), depth)
-            vecs = drain(p_tok[dp], p_fids[dp], live)
+            with jax.named_scope("fetch"):
+                wp = jnp.mod(r, depth)
+                p_ids = p_ids.at[wp].set(rnd.sel_ids)
+                p_fids = p_fids.at[wp].set(rnd.fetch_ids)
+                p_rm = p_rm.at[wp].set(rnd.result_mask)
+                p_tok = p_tok.at[wp].set(token)
+                live = r >= depth - 1
+                dp = jnp.mod(r - (depth - 1), depth)
+                vecs = drain(p_tok[dp], p_fids[dp], live)
             results, stats = retire(results, stats, p_ids[dp], p_rm[dp],
                                     vecs, live)
             return (nrnd, results, visited, stats, vc,
@@ -485,7 +523,8 @@ def filtered_search(
             rr = n_hops - (depth - 1) + j
             live = rr >= 0
             dp = jnp.mod(rr, depth)
-            vecs = drain(p_tok[dp], p_fids[dp], live)
+            with jax.named_scope("fetch"):
+                vecs = drain(p_tok[dp], p_fids[dp], live)
             results, stats = retire(results, stats, p_ids[dp], p_rm[dp],
                                     vecs, live)
         return SearchOutput(
@@ -504,7 +543,8 @@ def filtered_search(
             frontier, stats, vc, sel_ids, fetch_ids, tunnel_mask, result_mask = (
                 stage_a(frontier, visited, stats, vc)
             )
-            vecs, disk_nbrs = fetch(fetch_ids)  # (B, W, D), (B, W, R)
+            with jax.named_scope("fetch"):
+                vecs, disk_nbrs = fetch(fetch_ids)  # (B, W, D), (B, W, R)
             results, stats = retire(results, stats, sel_ids, result_mask,
                                     vecs, jnp.bool_(True))
             frontier, visited = expand(
@@ -543,21 +583,23 @@ def filtered_search(
             stage_a(frontier, visited, stats, vc)
         )
         # stage A: dispatch this round's read; neighbors come back now
-        token, disk_nbrs = submit(fetch_ids)
+        with jax.named_scope("fetch"):
+            token, disk_nbrs = submit(fetch_ids)
         frontier, visited = expand(
             frontier, visited, sel_ids, tunnel_mask, disk_nbrs
         )
-        wp = jnp.mod(r, depth)
-        p_ids = p_ids.at[wp].set(sel_ids)
-        p_fids = p_fids.at[wp].set(fetch_ids)
-        p_rm = p_rm.at[wp].set(result_mask)
-        p_tok = p_tok.at[wp].set(token)
-        # stage B: once the pipe is full, retire the oldest round (the
-        # drain is issued every round; `live` gates the warmup no-ops so
-        # the host interleaving stays fixed and deterministic)
-        live = r >= depth - 1
-        dp = jnp.mod(r - (depth - 1), depth)
-        vecs = drain(p_tok[dp], p_fids[dp], live)
+        with jax.named_scope("fetch"):
+            wp = jnp.mod(r, depth)
+            p_ids = p_ids.at[wp].set(sel_ids)
+            p_fids = p_fids.at[wp].set(fetch_ids)
+            p_rm = p_rm.at[wp].set(result_mask)
+            p_tok = p_tok.at[wp].set(token)
+            # stage B: once the pipe is full, retire the oldest round (the
+            # drain is issued every round; `live` gates the warmup no-ops so
+            # the host interleaving stays fixed and deterministic)
+            live = r >= depth - 1
+            dp = jnp.mod(r - (depth - 1), depth)
+            vecs = drain(p_tok[dp], p_fids[dp], live)
         results, stats = retire(results, stats, p_ids[dp], p_rm[dp],
                                 vecs, live)
         return (frontier, results, visited, stats, vc,
@@ -573,7 +615,8 @@ def filtered_search(
         rr = n_hops - (depth - 1) + j  # round to retire
         live = rr >= 0
         dp = jnp.mod(rr, depth)
-        vecs = drain(p_tok[dp], p_fids[dp], live)
+        with jax.named_scope("fetch"):
+            vecs = drain(p_tok[dp], p_fids[dp], live)
         results, stats = retire(results, stats, p_ids[dp], p_rm[dp],
                                 vecs, live)
 

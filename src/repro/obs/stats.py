@@ -60,8 +60,7 @@ def record_search_stats(reg: regm.MetricsRegistry, stats, *,
     contracts — ``search.ios{tier=disk}`` totals must equal the disk
     store's ``disk.records_read`` exactly, and
     ``search.ios + search.cache_hits`` vs ``search.tunnels`` is the
-    fetched-vs-tunneled split.  Histograms carry the per-query
-    distributions the report CLI renders.  Returns ``stats_totals``.
+    fetched-vs-tunneled split.  Returns ``stats_totals``.
     """
     t = stats_totals(stats)
     labels = {"mode": mode, "tier": tier}
@@ -76,10 +75,4 @@ def record_search_stats(reg: regm.MetricsRegistry, stats, *,
         reg.counter("search.degraded_queries", **labels).inc(
             int((np.asarray(stats.n_degraded) > 0).sum())
         )
-    h_ios = reg.histogram("search.ios_per_query", mode=mode)
-    h_hops = reg.histogram("search.hops_per_query", mode=mode)
-    for v in np.asarray(stats.n_ios).tolist():
-        h_ios.observe(v)
-    for v in np.asarray(stats.n_hops).tolist():
-        h_hops.observe(v)
     return t

@@ -15,20 +15,30 @@ clock steps can never corrupt a duration) and publishes it two ways:
     histogram families need fixed, bounded label sets; ``name`` itself
     is reserved for the registry API).
 
+An enabled span also enters a ``jax.profiler.TraceAnnotation`` of the
+same name, so under a running ``jax.profiler`` trace it lands on the
+host plane, on the thread that ran it and on the timeline of the device
+ops: a device op that waits on a host callback can be laid beside what
+the host was doing meanwhile (``bench/stages.py`` does, after finding
+how far the host's events sit from the device's on that timeline).
+
 Overhead budget (documented, and pinned by the tier-1 overhead guard):
 
   * **disabled** (the default): ``span()`` is one attribute read, one
     branch, and a shared no-op context manager — near-zero, safe to
     leave in the hottest host callback.
-  * **enabled**: two ``perf_counter`` calls plus a ring append and one
-    histogram observe per recorded span, ~1-2us on commodity CPUs —
+  * **enabled**: two ``perf_counter`` calls, a ring append, one
+    histogram observe and a profiler annotation per recorded span, a
+    few us on commodity CPUs (the annotation adds under 1us while no
+    profiler trace runs) —
     <2% of even a page-cache-served 4 KB ``preadv`` round, which is the
     cheapest stage we time.  The ``sample_rate`` knob (1-in-N per
     thread, deterministic) cuts it further for high-frequency spans.
 
 Pre-measured durations (e.g. the serving dispatcher computes queue-wait
 arithmetic itself) enter through ``trace.record(name, dur_s, ...)`` —
-same ring, same histogram family, no double clocking.
+same ring, same histogram family, no double clocking; such a span is
+not annotated in a profiler trace, which cannot be backdated.
 """
 from __future__ import annotations
 
@@ -53,17 +63,28 @@ class _NopSpan:
 
 
 _NOP = _NopSpan()
+_Annotation = None  # jax.profiler.TraceAnnotation, imported by the first span
+
+
+def _annotation_cls():
+    global _Annotation
+    if _Annotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _Annotation = TraceAnnotation
+    return _Annotation
 
 
 class _Ring:
     """Fixed-capacity overwrite-oldest span buffer (single-writer)."""
 
-    __slots__ = ("buf", "cap", "i")
+    __slots__ = ("buf", "cap", "i", "seen")
 
     def __init__(self, cap: int):
         self.buf: list = []
         self.cap = cap
         self.i = 0
+        self.seen = 0  # spans offered, sampled out or not
 
     def push(self, item) -> None:
         if len(self.buf) < self.cap:
@@ -80,21 +101,23 @@ class _Ring:
 
 
 class _Span:
-    __slots__ = ("_tracer", "name", "labels", "t0")
+    __slots__ = ("_tracer", "name", "labels", "t0", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, labels: dict):
         self._tracer = tracer
         self.name = name
         self.labels = labels
+        self._ann = _annotation_cls()(name)
 
     def __enter__(self):
+        self._ann.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        self._tracer._commit(
-            self.name, self.labels, self.t0, time.perf_counter() - self.t0
-        )
+        dur = time.perf_counter() - self.t0
+        self._ann.__exit__(*exc)
+        self._tracer._commit(self.name, self.labels, self.t0, dur)
         return False
 
 
@@ -139,7 +162,8 @@ class Tracer:
         return _Span(self, name, labels)
 
     def record(self, name: str, duration_s: float, **labels) -> None:
-        """Publish an externally measured duration as a span."""
+        """Publish an externally measured duration as a span: ring and
+        histogram only, since a profiler annotation cannot be backdated."""
         if not self.enabled:
             return
         self._commit(name, labels, time.perf_counter() - duration_s,
@@ -149,13 +173,15 @@ class Tracer:
         tls = self._tls
         ring = getattr(tls, "ring", None)
         if ring is None:
-            ring = tls.ring = _Ring(self._ring_size)
-            tls.n = 0
+            # a host callback's thread gets fresh thread-local state on
+            # every call, so the ring is found again by thread, not kept
             t = threading.current_thread()
             with self._rings_lock:
-                self._rings[f"{t.name}-{t.ident}"] = ring
-        n = tls.n
-        tls.n = n + 1
+                ring = self._rings.setdefault(f"{t.name}-{t.ident}",
+                                              _Ring(self._ring_size))
+            tls.ring = ring
+        n = ring.seen
+        ring.seen = n + 1
         if n % self.sample_every:
             return
         ring.push((name, labels, t0, dur))

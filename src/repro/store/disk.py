@@ -842,7 +842,14 @@ class DiskRecordStore:
         )
         # ordered: fetches must all execute (and in program order) so the
         # measured counters reconcile exactly with SearchStats.n_ios
-        return io_callback(self._host_fetch, out_shapes, ids, ordered=True)
+        return io_callback(self._host_fetch_sync, out_shapes, ids, ordered=True)
+
+    def _host_fetch_sync(self, ids: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+        """``_host_fetch`` as the search loop's synchronous callback, under
+        the ``disk.fetch`` span (the reader pool's calls are not: their
+        reads are ``disk.preadv`` spans and their wait ``disk.drain_wait``)."""
+        with obs.trace.span("disk.fetch", store=self._obs_label):
+            return self._host_fetch(ids)
 
     def fetch_fn(self):
         return self._fetch
@@ -866,16 +873,20 @@ class DiskRecordStore:
 
         The neighbor lists come from the adjacency sidecar immediately —
         the caller can expand the frontier and dispatch the next beam
-        while this round's record read is still in flight on the pool."""
+        while this round's record read is still in flight on the pool.
+        The ``disk.submit`` span covers the whole body."""
+        with obs.trace.span("disk.submit", store=self._obs_label):
+            return self._submit_body(ids)
+
+    def _submit_body(self, ids: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
         ids = np.asarray(ids)
         valid = ids >= 0
         flat = np.clip(ids, 0, self.n - 1).reshape(-1)
         nbrs = np.full(ids.shape + (self.degree,), -1, np.int32)
         vmask = valid.reshape(-1)
-        with obs.trace.span("disk.submit", store=self._obs_label):
-            if vmask.any():
-                adj = self._adjacency_host()
-                nbrs.reshape(-1, self.degree)[vmask] = adj[flat[vmask]]
+        if vmask.any():
+            adj = self._adjacency_host()
+            nbrs.reshape(-1, self.degree)[vmask] = adj[flat[vmask]]
         job_ids = np.array(ids, copy=True)  # the callback buffer is reused
         with self._lock:
             if self._pool is None:
@@ -903,10 +914,15 @@ class DiskRecordStore:
         """Retire one submitted round: block until its read completed and
         return the record vectors.  ``flag=False`` is the pipeline-warmup
         no-op (the loop issues a fixed drain per round; early rounds have
-        nothing to retire) — it returns zeros without touching the queue."""
-        vecs = np.zeros(np.asarray(ids).shape + (self.dim,), np.float32)
+        nothing to retire) — it returns zeros without touching the queue.
+        A live drain runs under the ``disk.drain`` span, the wait for the
+        read under ``disk.drain_wait`` inside it."""
         if not bool(flag):
-            return vecs
+            return np.zeros(np.asarray(ids).shape + (self.dim,), np.float32)
+        with obs.trace.span("disk.drain", store=self._obs_label):
+            return self._drain_body(token)
+
+    def _drain_body(self, token: np.ndarray) -> np.ndarray:
         with self._lock:
             fut = self._pending.pop(int(token), None)
             if fut is not None:
