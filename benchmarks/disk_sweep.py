@@ -17,9 +17,10 @@ Two reconciliation contracts, both enforced nightly:
     filter-gated nodes never reach the file.
   * physical (coalesced): ``unique_sectors_read <= sum(n_ios)`` (equality
     iff no round fetched the same record for two queries at once), and
-    one vectored syscall per search round on the preadv path
-    (``syscalls == read_rounds``) or one per merged range on the
-    fallback (``syscalls == ranges_read``).
+    one vectored syscall per search round, plus one per hole wider than
+    the gap bound, on the preadv path (``syscalls == read_rounds +
+    split_gaps``) or one per merged range on the fallback (``syscalls ==
+    ranges_read``).
 
 Emits the benchmark-contract CSV ``name,us_per_call,derived``:
 
@@ -131,15 +132,13 @@ def sweep_disk(ctx, *, budgets=BUDGET_RECORDS, modes=MODES, search_l=100):
             ids_match &= bool(np.array_equal(ids, mem_ids))
             # physical contracts: dedup never reads more than requested;
             # the preadv path spends one vectored syscall per round (per
-            # touched segment), the pread fallback one per merged range
+            # touched segment) plus one per unbridged hole, the pread
+            # fallback one per merged range
             unique_ok &= d["unique_sectors_read"] <= d["records_read"]
             if store.io_mode == "preadv":
-                # == read_rounds on this (unsharded) index; a sharded one
-                # may spend up to one call per touched segment per round
-                syscall_ok &= (
-                    d["read_rounds"] <= d["syscalls"]
-                    <= d["read_rounds"] * store.n_shards
-                )
+                # on this (unsharded) index: one call per round, plus one
+                # per hole wider than the gap bound
+                syscall_ok &= d["syscalls"] == d["read_rounds"] + d["split_gaps"]
             elif store.io_mode == "pread":
                 syscall_ok &= d["syscalls"] == d["ranges_read"]
             else:  # gather oracle issues no explicit syscalls
@@ -176,7 +175,7 @@ def sweep_disk(ctx, *, budgets=BUDGET_RECORDS, modes=MODES, search_l=100):
         c = store.io_counters()
         mirrored = ("records_read", "pages_read", "bytes_read",
                     "unique_sectors_read", "ranges_read", "syscalls",
-                    "fetch_rounds", "read_rounds")
+                    "fetch_rounds", "read_rounds", "split_gaps")
         ok = all(reg.family_total(f"disk.{k}") == c[k] for k in mirrored)
         rows.append(dict(name="obs_store_reconciled", lat1_us=0.0,
                          derived=float(ok)))

@@ -48,9 +48,13 @@ class EngineConfig:
     store_tier: str = "memory"  # memory | host | disk (disk needs a path)
     # disk tier: bound on preadv gap bridging, in sectors — a merged read
     # never bridges a hole wider than this (it splits into another
-    # vectored call instead).  Negative = unbounded (favor syscall count),
-    # 0 = never bridge (favor zero read amplification).
-    max_gap_sectors: int = -1
+    # vectored call instead).  None = derived from the file: holes up to
+    # store.disk.MAX_BRIDGE_BYTES (128 KiB) over the sector size, 32
+    # sectors at 4 KiB records; negative = unbounded (one call per round,
+    # reading from its first record to its last); 0 = never bridge.  A
+    # reading knob, not part of the saved index: load() ignores the
+    # stored value and takes only its caller's.
+    max_gap_sectors: int | None = None
     cache_budget_bytes: int = 0  # hot-record cache size (0 disables the tier)
     cache_policy: str = "visit_freq"  # visit_freq | bfs | adaptive
     refresh_every: int = 4  # adaptive: batches between hot-set refreshes
@@ -317,7 +321,8 @@ class GateANNEngine:
         (or keyword overrides) change the *runtime* knobs — e.g.
         ``store_tier="disk"`` serves records off the file with measured
         I/O, ``r_max`` re-slices the neighbor store, ``cache_*`` attaches
-        a cache tier.
+        a cache tier.  ``max_gap_sectors`` is the exception: it comes only
+        from the overrides, else it is derived from the file's sector size.
 
         ``warm_disk=True`` starts a background sequential re-read of the
         record segment files right after the disk store opens, so the OS
@@ -341,8 +346,11 @@ class GateANNEngine:
                 f"valid fields: {sorted(known)}"
             )
         # stored configs may carry fields from other format versions —
-        # tolerate those, but never silently drop an explicit override
-        cfg = {k: v for k, v in (h.config or {}).items() if k in known}
+        # tolerate those, but never silently drop an explicit override.
+        # The gap bound is how the disk tier reads, not a property of the
+        # index: indexes saved with the old unbounded default store -1.
+        cfg = {k: v for k, v in (h.config or {}).items()
+               if k in known and k != "max_gap_sectors"}
         cfg.update(user)
         config = EngineConfig(**cfg)
         neighbors = jnp.asarray(idx.neighbors(), jnp.int32)

@@ -14,13 +14,13 @@ The read path is **coalesced**, the way PipeANN keeps W reads in flight
 instead of issuing them one by one: each round's beam is sorted,
 deduplicated, and merged into contiguous sector ranges, then fetched as
 
-  * ``io_mode="preadv"`` (default where available) — ONE vectored
-    ``os.preadv`` per round and segment: wanted ranges scatter directly
-    into the output buffer, the gaps between them land in a reusable
-    discard buffer (counted in ``gap_sectors_read`` — the page-cache
-    over-read this trade buys its single syscall with; production
-    deployments bound it by sharding, see below).  Rounds wider than
-    ``IOV_MAX`` split into multiple counted calls.
+  * ``io_mode="preadv"`` (default where available) — vectored
+    ``os.preadv`` calls per round and segment: wanted ranges scatter
+    directly into the output buffer, and a hole between two of them
+    that is cheaper to read than to skip lands in a reusable discard
+    buffer (counted in ``gap_sectors_read``); a wider hole starts
+    another call (counted in ``split_gaps``).  Groups wider than
+    ``IOV_MAX`` split into further counted calls.
   * ``io_mode="pread"`` — one ``os.pread`` per merged range (no
     over-read; ``syscalls == ranges_read``).
   * ``io_mode="gather"`` — the legacy per-record memmap fancy-gather
@@ -40,18 +40,25 @@ re-wrap does — must not lose updates):
     discipline check (cache hits and filter-gated nodes never reach the
     file).
   * physical — ``unique_sectors_read`` / ``ranges_read`` / ``syscalls``
-    / ``gap_sectors_read`` / ``read_rounds``: what the coalesced reader
-    actually did.  Contract: ``unique_sectors_read <= records_read``
-    with equality when a round has no intra-round duplicates, and on the
-    preadv path ``syscalls == read_rounds`` (one vectored read per round
-    per touched segment).
+    / ``gap_sectors_read`` / ``read_rounds`` / ``split_gaps``: what the
+    coalesced reader actually did.  Contract: ``unique_sectors_read <=
+    records_read`` with equality when a round has no intra-round
+    duplicates, and on the preadv path, without short reads or
+    ``IOV_MAX`` splits, one vectored read per touched segment per round
+    plus one per hole left unbridged — ``syscalls == read_rounds +
+    split_gaps`` on an unsharded index.
 
-Bridged gaps are bounded by ``max_gap_sectors``: when the hole between
-two wanted ranges exceeds the bound, the round splits into another
-vectored call instead of reading through it — the syscall-count vs
-read-amplification trade as an explicit knob (``None``/negative =
-unbounded, today's single-call behavior; ``0`` = never bridge, one call
-per merged range).
+Bridged holes are bounded by ``max_gap_sectors``: a hole wider than the
+bound is not read through; the round starts another vectored call after
+it.  By default (``None``) the bound is derived from the file: bridge a
+hole only while reading it costs less than one more random read, taken
+as ``MAX_BRIDGE_BYTES`` (128 KiB, the kernel's default read-ahead
+window) over the record sector size — 32 sectors at 4 KiB records.  A
+negative bound bridges every hole (one call per round and segment, at
+the cost of reading from a round's first record to its last); ``0``
+never bridges (one call per merged range, no over-read).  The bound is
+how this tier reads, not a property of a saved index, so
+``GateANNEngine.load`` takes it only from its caller.
 
 **Asynchronous pipeline interface** (the PipeANN overlap, done host-side):
 ``submit(ids) -> (token, nbrs)`` enqueues the round's coalesced sector
@@ -131,6 +138,9 @@ _HAVE_PREADV = hasattr(os, "preadv")
 _HAVE_PREAD = hasattr(os, "pread")
 _IOV_MAX = 1000  # stay under the kernel's 1024-iovec ceiling
 _GAP_CHUNK = 1 << 20  # discard-buffer granularity for bridged gaps
+# the widest hole the preadv path reads through by default: past the
+# kernel's default read-ahead window, one more random read is cheaper
+MAX_BRIDGE_BYTES = 128 << 10
 
 IO_MODES = ("preadv", "pread", "gather")
 
@@ -399,10 +409,11 @@ class DiskRecordStore:
         if io_mode == "pread" and not _HAVE_PREAD:
             io_mode = "gather"
         self.io_mode = io_mode
-        # preadv gap-bridging bound, in sectors (None/negative = unbounded)
-        if max_gap_sectors is not None and max_gap_sectors < 0:
-            max_gap_sectors = None
-        self.max_gap_sectors = max_gap_sectors
+        # preadv gap-bridging bound, in sectors: None = derived from the
+        # sector size, negative = unbounded (resolved to -1), 0 = never
+        if max_gap_sectors is None:
+            max_gap_sectors = MAX_BRIDGE_BYTES // self.sector_bytes
+        self.max_gap_sectors = max(int(max_gap_sectors), -1)
         self.reader_threads = max(int(reader_threads), 1)
         # resilience policy: how transient read errors are retried and what
         # happens when retries exhaust / the round deadline trips.  All
@@ -447,6 +458,7 @@ class DiskRecordStore:
             "ranges_read": mk("disk.ranges_read"),
             "syscalls": mk("disk.syscalls"),
             "gap_sectors_read": mk("disk.gap_sectors_read"),
+            "split_gaps": mk("disk.split_gaps"),
             "fetch_rounds": mk("disk.fetch_rounds"),
             "read_rounds": mk("disk.read_rounds"),
             "overlapped_rounds": mk("disk.overlapped_rounds"),
@@ -690,13 +702,12 @@ class DiskRecordStore:
                         self._fail_span(ok, io, pos, pos + int(count), e)
                     pos += int(count)
                 continue
-            # preadv: one vectored call per round and segment — wanted
-            # ranges scatter straight into the output, bridged gaps land
-            # in the discard buffer.  A gap wider than max_gap_sectors is
-            # never bridged: the round splits into another vectored call
-            # there instead, trading a syscall for the over-read.  Groups
-            # are collected first, then issued, so a failed group maps
-            # cleanly to its wanted-record span.
+            # preadv: wanted ranges scatter straight into the output,
+            # bridged gaps land in the discard buffer.  A gap wider than
+            # max_gap_sectors is never bridged: the round splits into
+            # another vectored call there instead, trading a syscall for
+            # the over-read.  Groups are collected first, then issued, so
+            # a failed group maps cleanly to its wanted-record span.
             max_gap = self.max_gap_sectors
             groups = []  # (views, group_start_sector, pos_lo, pos_hi)
             views = []
@@ -705,7 +716,8 @@ class DiskRecordStore:
             gpos_lo = pos
             for start, count in ranges:
                 gap = 0 if prev_end is None else int(start - prev_end)
-                if views and max_gap is not None and gap > max_gap:
+                if views and 0 <= max_gap < gap:
+                    io["split_gaps"] += 1
                     groups.append((views, group_start, gpos_lo, pos))
                     views = []
                     prev_end = None
@@ -740,7 +752,7 @@ class DiskRecordStore:
         vecs = np.zeros(ids.shape + (self.dim,), np.float32)
         nbrs = np.full(ids.shape + (self.degree,), -1, np.int32)
         m = int(vmask.sum())
-        io = {"syscalls": 0, "ranges": 0, "gap_sectors": 0,
+        io = {"syscalls": 0, "ranges": 0, "gap_sectors": 0, "split_gaps": 0,
               "retried_ios": 0, "retry_exhausted": 0, "deadline_trips": 0}
         u = 0
         n_degraded = 0
@@ -761,6 +773,7 @@ class DiskRecordStore:
                     self.ranges_read += io["ranges"]
                     self.syscalls += io["syscalls"]
                     self.gap_sectors_read += io["gap_sectors"]
+                    self.split_gaps += io["split_gaps"]
                     self.retried_ios += io["retried_ios"]
                     self.retry_exhausted += io["retry_exhausted"]
                     self.deadline_trips += io["deadline_trips"]
@@ -771,6 +784,7 @@ class DiskRecordStore:
                     c["ranges_read"].inc(io["ranges"])
                     c["syscalls"].inc(io["syscalls"])
                     c["gap_sectors_read"].inc(io["gap_sectors"])
+                    c["split_gaps"].inc(io["split_gaps"])
                     c["retried_ios"].inc(io["retried_ios"])
                     c["retry_exhausted"].inc(io["retry_exhausted"])
                     c["deadline_trips"].inc(io["deadline_trips"])
@@ -805,6 +819,7 @@ class DiskRecordStore:
             self.ranges_read += io["ranges"]
             self.syscalls += io["syscalls"]
             self.gap_sectors_read += io["gap_sectors"]
+            self.split_gaps += io["split_gaps"]
             self.fetch_rounds += 1
             self.read_rounds += int(u > 0)
             self.retried_ios += io["retried_ios"]
@@ -823,6 +838,7 @@ class DiskRecordStore:
             c["ranges_read"].inc(io["ranges"])
             c["syscalls"].inc(io["syscalls"])
             c["gap_sectors_read"].inc(io["gap_sectors"])
+            c["split_gaps"].inc(io["split_gaps"])
             c["fetch_rounds"].inc()
             c["read_rounds"].inc(int(u > 0))
             if io["retried_ios"]:
@@ -1063,6 +1079,7 @@ class DiskRecordStore:
         self.ranges_read = 0
         self.syscalls = 0
         self.gap_sectors_read = 0
+        self.split_gaps = 0  # holes the gap bound declined to bridge
         self.fetch_rounds = 0
         self.read_rounds = 0
         # pipeline overlap (advanced by submit; _inflight itself is live
@@ -1095,6 +1112,7 @@ class DiskRecordStore:
                 "ranges_read": self.ranges_read,
                 "syscalls": self.syscalls,
                 "gap_sectors_read": self.gap_sectors_read,
+                "split_gaps": self.split_gaps,
                 "fetch_rounds": self.fetch_rounds,
                 "read_rounds": self.read_rounds,
                 "inflight_depth_max": self.inflight_depth_max,

@@ -16,8 +16,13 @@ Contract under test (store/disk.py):
     (``unique_sectors_read``/``ranges_read``/``syscalls``/
     ``gap_sectors_read``) count what the reader did.
     ``unique_sectors_read <= records_read`` with equality iff the round
-    had no duplicates; preadv spends ``syscalls == read_rounds`` (per
-    segment), pread ``syscalls == ranges_read``, gather 0.
+    had no duplicates; preadv spends ``syscalls == read_rounds +
+    split_gaps`` (per segment; ``split_gaps`` counts the holes wider than
+    ``max_gap_sectors``, left unbridged), pread ``syscalls ==
+    ranges_read``, gather 0.
+  * The gap bound defaults to ``MAX_BRIDGE_BYTES`` over the sector size,
+    and ``GateANNEngine.load`` takes it from its caller alone, never from
+    the config stored in the index.
   * Counters are guarded by a lock — concurrent fetches through one
     shared store must not lose updates, and reset is atomic.
 """
@@ -31,9 +36,17 @@ import pytest
 
 from repro.core import GateANNEngine, SearchConfig
 from repro.store import DiskRecordStore, is_lazy_host, merge_ranges
+from repro.store.disk import MAX_BRIDGE_BYTES
+from repro.store.format import read_header
 
 RECORD = 4096  # tiny-corpus records round up to one 4 KB sector
-IO_MODES = ("preadv", "pread", "gather")
+DERIVED = MAX_BRIDGE_BYTES // RECORD  # the default gap bound, in sectors
+STORES = {  # case -> DiskRecordStore.open keywords
+    "preadv": dict(io_mode="preadv", max_gap_sectors=-1),  # unbounded
+    "preadv-derived": dict(io_mode="preadv"),  # the default bound
+    "pread": dict(io_mode="pread"),
+    "gather": dict(io_mode="gather"),
+}
 
 
 @pytest.fixture(scope="module")
@@ -45,7 +58,7 @@ def index_path(tiny_engine, tmp_path_factory):
 
 @pytest.fixture(scope="module")
 def stores(index_path):
-    return {m: DiskRecordStore.open(index_path, io_mode=m) for m in IO_MODES}
+    return {k: DiskRecordStore.open(index_path, **kw) for k, kw in STORES.items()}
 
 
 def _beam(n, rng, b=7, w=9):
@@ -59,6 +72,12 @@ def _beam(n, rng, b=7, w=9):
     return ids
 
 
+def _holes(ids) -> np.ndarray:
+    """Sizes, in sectors, of the holes between a beam's merged ranges."""
+    r = merge_ranges(np.unique(ids[ids >= 0]))
+    return r[1:, 0] - (r[:-1, 0] + r[:-1, 1])
+
+
 def test_merge_ranges_unit():
     got = merge_ranges(np.asarray([0, 1, 2, 5, 7, 8, 9]))
     np.testing.assert_array_equal(got, [[0, 3], [5, 1], [7, 3]])
@@ -66,7 +85,7 @@ def test_merge_ranges_unit():
     np.testing.assert_array_equal(merge_ranges(np.asarray([4])), [[4, 1]])
 
 
-@pytest.mark.parametrize("io_mode", IO_MODES)
+@pytest.mark.parametrize("io_mode", tuple(STORES))
 def test_duplicate_heavy_fetch_parity_and_counters(stores, tiny_engine, io_mode):
     store = stores[io_mode]
     ref_fetch = tiny_engine.record_store.fetch_fn()
@@ -87,9 +106,17 @@ def test_duplicate_heavy_fetch_parity_and_counters(stores, tiny_engine, io_mode)
         assert d["bytes_read"] == m * store.sector_bytes
         assert d["unique_sectors_read"] == u < m  # the beam is dup-heavy
         assert d["fetch_rounds"] == 1 and d["read_rounds"] == 1
-        if io_mode == "preadv":
-            assert d["syscalls"] == 1  # ONE vectored read for the round
-        elif io_mode == "pread":
+        if store.io_mode == "preadv":
+            # every hole up to the bound is read through, every wider one
+            # starts another vectored call
+            holes = _holes(ids)
+            bound = store.max_gap_sectors if store.max_gap_sectors >= 0 else np.inf
+            assert d["gap_sectors_read"] == int(holes[holes <= bound].sum())
+            assert d["split_gaps"] == int((holes > bound).sum())
+            assert d["syscalls"] == d["read_rounds"] + d["split_gaps"]
+            if io_mode == "preadv":  # unbounded
+                assert d["syscalls"] == 1  # ONE vectored read for the round
+        elif store.io_mode == "pread":
             assert d["syscalls"] == d["ranges_read"]
         else:
             assert d["syscalls"] == 0 and d["gap_sectors_read"] == 0
@@ -114,16 +141,17 @@ def test_all_invalid_beam_reads_nothing(stores):
         assert d["fetch_rounds"] == 1 and d["read_rounds"] == 0, io_mode
 
 
-@pytest.mark.parametrize("io_mode", ("pread", "gather"))
+@pytest.mark.parametrize("io_mode", ("pread", "gather", "preadv"))
 def test_search_bit_identical_across_io_modes(index_path, tiny_corpus, io_mode):
-    """Full loop: the non-default read paths return the same search output
-    as the default (preadv) disk engine, uncached and cached."""
+    """Full loop: the non-default read paths (and preadv unbounded) return
+    the same search output as the default disk engine, uncached and
+    cached."""
     import dataclasses
 
     _, _, queries = tiny_corpus
     base = GateANNEngine.load(index_path, store_tier="disk")
     alt = dataclasses.replace(
-        base, record_store=DiskRecordStore.open(index_path, io_mode=io_mode)
+        base, record_store=DiskRecordStore.open(index_path, **STORES[io_mode])
     )
     cfg = SearchConfig(mode="gate", search_l=48, beam_width=4)
     tgt = np.zeros(queries.shape[0], np.int32)
@@ -189,7 +217,7 @@ def test_max_gap_sectors_bounds_bridging(index_path, stores):
         np.asarray([[0, 2, 10, -1]], np.int32))
     # ranges (0,1) (2,1) (10,1): gaps of 1 and 7 sectors
     cases = {
-        None: dict(syscalls=1, gap=8),   # bridge everything, one preadv
+        -1: dict(syscalls=1, gap=8),     # bridge everything, one preadv
         7: dict(syscalls=1, gap=8),      # bound == widest gap: still one
         2: dict(syscalls=2, gap=1),      # bridge the 1-gap, split at the 7
         0: dict(syscalls=3, gap=0),      # never bridge: one call per range
@@ -204,9 +232,53 @@ def test_max_gap_sectors_bounds_bridging(index_path, stores):
         assert c["syscalls"] == want["syscalls"], (bound, c)
         assert c["gap_sectors_read"] == want["gap"], (bound, c)
         assert c["ranges_read"] == 3, (bound, c)
+        assert c["split_gaps"] == want["syscalls"] - 1, (bound, c)
         store.close()
-    # negative = unbounded (the EngineConfig encoding of None)
-    assert DiskRecordStore.open(index_path, max_gap_sectors=-1).max_gap_sectors is None
+    # any negative bound is unbounded, resolved to -1
+    assert DiskRecordStore.open(index_path, max_gap_sectors=-5).max_gap_sectors == -1
+
+
+@pytest.mark.parametrize("hole, split", [(DERIVED, 0), (DERIVED + 1, 1)],
+                         ids=["at-bound", "one-wider"])
+def test_derived_bound_bridges_up_to_max_bridge_bytes(index_path, stores,
+                                                      hole, split):
+    """The default bound is MAX_BRIDGE_BYTES over the sector size: a hole
+    of exactly the bound is read through, one sector wider is split."""
+    store = DiskRecordStore.open(index_path, io_mode="preadv")
+    assert store.max_gap_sectors == DERIVED == 32
+    ids = np.asarray([[0, hole + 1, -1]], np.int32)
+    vecs, nbrs = store._host_fetch(ids)
+    ref_v, ref_n = stores["gather"]._host_fetch(ids)
+    np.testing.assert_array_equal(vecs, ref_v)
+    np.testing.assert_array_equal(nbrs, ref_n)
+    c = store.io_counters()
+    assert c["split_gaps"] == split
+    assert c["gap_sectors_read"] == (0 if split else hole)
+    assert c["syscalls"] == c["read_rounds"] + c["split_gaps"] == 1 + split
+    store.close()
+
+
+@pytest.mark.parametrize("override, want", [({}, DERIVED),
+                                            ({"max_gap_sectors": -1}, -1),
+                                            ({"max_gap_sectors": 0}, 0)],
+                         ids=["derived", "unbounded", "never"])
+def test_load_takes_gap_bound_from_caller_only(tiny_engine, tmp_path,
+                                               override, want):
+    """An index saved with the old unbounded default (-1 in its stored
+    config) loads with the derived bound; an explicit override wins."""
+    import dataclasses
+
+    path = str(tmp_path / "old.gann")
+    old = dataclasses.replace(
+        tiny_engine,
+        config=dataclasses.replace(tiny_engine.config, max_gap_sectors=-1))
+    old.save(path)
+    assert read_header(path).config["max_gap_sectors"] == -1
+    eng = GateANNEngine.load(path, store_tier="disk", **override)
+    assert eng.config.max_gap_sectors == override.get("max_gap_sectors")
+    assert eng.record_store.max_gap_sectors == want
+    assert eng.memory_report()["disk_max_gap_sectors"] == want
+    eng.record_store.close()
 
 
 def test_max_gap_search_parity(index_path, tiny_corpus):

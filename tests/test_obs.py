@@ -274,8 +274,11 @@ def test_disk_search_reconciles_registry(tiny_engine, tiny_corpus, tmp_path):
     # three-way: registry == measured == modeled
     assert reg.family_total("disk.records_read") == c["records_read"] == ios
     for key in ("pages_read", "bytes_read", "unique_sectors_read",
-                "ranges_read", "syscalls", "fetch_rounds", "read_rounds"):
+                "ranges_read", "syscalls", "fetch_rounds", "read_rounds",
+                "split_gaps", "gap_sectors_read"):
         assert reg.family_total(f"disk.{key}") == c[key], key
+    if store.io_mode == "preadv":
+        assert c["syscalls"] == c["read_rounds"] + c["split_gaps"]
     assert reg.family_total("search.ios", tier="disk", mode="gate") == ios
     assert reg.family_total("search.queries") == queries.shape[0]
     # fetched-vs-tunneled split is non-trivial in gate mode

@@ -252,18 +252,30 @@ def test_sharded_roundtrip_bit_identical(sharded_path, tiny_engine,
 
 def test_sharded_disk_counters(sharded_path, tiny_corpus):
     """Coalescing works per segment: unique <= requested still holds and
-    preadv spends at most one vectored call per touched segment per round."""
+    preadv spends one vectored call per touched segment per round, plus
+    one per hole the gap bound left unbridged."""
     _, _, queries = tiny_corpus
     eng = GateANNEngine.load(sharded_path, store_tier="disk")
     store = eng.record_store
     assert store.n_shards == 3
+    touched = []  # segments each read round touches
+    read_unique = store._read_unique
+
+    def counting_read(uniq, io):
+        seg = np.searchsorted(store._row_starts, uniq, side="right") - 1
+        touched.append(np.unique(seg).size)
+        return read_unique(uniq, io)
+
+    store._read_unique = counting_read
     out = _search(eng, queries, "gate")
     np.asarray(out.ids)  # materialize => all callbacks ran
     c = store.io_counters()
     assert c["records_read"] == int(np.sum(np.asarray(out.stats.n_ios)))
     assert 0 < c["unique_sectors_read"] <= c["records_read"]
+    assert len(touched) == c["read_rounds"]
+    assert c["read_rounds"] <= sum(touched) <= c["read_rounds"] * 3
     if store.io_mode == "preadv":
-        assert c["read_rounds"] <= c["syscalls"] <= c["read_rounds"] * 3
+        assert c["syscalls"] == sum(touched) + c["split_gaps"]
     # footprint spans the main file plus every segment
     assert store.index_bytes() > os.path.getsize(sharded_path)
 
