@@ -1,25 +1,31 @@
-"""Corpora, labels and query streams of the benchmark, made from the run's seed.
+"""Corpora and query streams of the benchmark, made from the run's seed.
 
-The generators are copies of those in ``src/repro/data`` (``_gmm``,
-``make_bigann_like``, ``make_deep_like``, ``uniform_labels``,
-``zipf_labels``, ``make_queries``), taking a ``numpy.random.Generator``
-instead of an int seed.  They live here so
+A configuration's corpus comes from the generator its ``corpus`` group
+names, ``bench/corpora/<generator>.py``; its records' attributes and
+each request's predicate come from its filter schema,
+``bench/filters/<filter>.py``.  The generators are copies of those in
+``src/repro/data`` (``_gmm``, ``make_bigann_like``, ``make_deep_like``,
+``uniform_labels``, ``zipf_labels``, ``make_queries``), taking a
+``numpy.random.Generator`` instead of an int seed.  They live here so
 that a change to the program cannot move the yardstick.
 
 Every random draw comes from one named stream of a seed (``streams``),
-so a new stream can be added without moving the others.  The corpus,
-its labels and the index build come from the configuration's
+so a new stream can be added at the end without moving the others.  The
+corpus, its attributes and the index build come from the configuration's
 ``data_seed``: a deployment's data is fixed, and seeds that each drew a
 corpus of their own were seen to change the work of a run by up to 30%.
-The requests (query vectors and their labels) and the sample that is
-checked come from the run's ``--seed``.  Seeds
-may be any non-negative integer, larger than 32 bits included.
+The requests (query vectors and their predicates), the arrival times of
+an open loop and the sample that is checked come from the run's
+``--seed``.  Seeds may be any non-negative integer, larger than 32 bits
+included.
 """
 from __future__ import annotations
 
 import numpy as np
 
-STREAMS = ("corpus", "labels", "queries", "requests", "sample", "build")
+from bench import spec as specm
+
+STREAMS = ("corpus", "labels", "queries", "requests", "sample", "build", "arrivals")
 
 
 def streams(seed: int) -> dict[str, np.random.Generator]:
@@ -37,63 +43,23 @@ def build_seed(seed: int) -> int:
 
 # -- corpora -----------------------------------------------------------------
 
-def _gmm(n: int, dim: int, n_clusters: int, rng: np.random.Generator,
-         spread: float = 0.35) -> np.ndarray:
+def gmm(n: int, dim: int, n_clusters: int, rng: np.random.Generator,
+        spread: float = 0.35) -> np.ndarray:
+    """``n`` points around ``n_clusters`` unit-normal centres: the shared
+    body of the clustered generators."""
     centers = rng.normal(0.0, 1.0, size=(n_clusters, dim))
     assign = rng.integers(0, n_clusters, size=n)
     x = centers[assign] + rng.normal(0.0, spread, size=(n, dim))
     return x.astype(np.float32)
 
 
-def bigann_like(n: int, dim: int, rng: np.random.Generator,
-                n_clusters: int = 64) -> np.ndarray:
-    """uint8-range clustered vectors (SIFT-like), stored as float32."""
-    x = _gmm(n, dim, n_clusters, rng)
-    x = x - x.min()
-    x = x / x.max() * 255.0
-    return np.round(x).astype(np.float32)
-
-
-def deep_like(n: int, dim: int, rng: np.random.Generator,
-              n_clusters: int = 64) -> np.ndarray:
-    """Unit-norm float32 descriptors (DEEP-like)."""
-    x = _gmm(n, dim, n_clusters, rng)
-    x /= np.linalg.norm(x, axis=1, keepdims=True) + 1e-9
-    return x.astype(np.float32)
-
-
-CORPORA = {"bigann_like": bigann_like, "deep_like": deep_like}
-
-
-def corpus(config: dict, rng: np.random.Generator) -> np.ndarray:
-    """The configuration's ``n_vectors`` records, by its ``corpus`` group."""
-    spec = config["corpus"]
-    return CORPORA[spec["generator"]](
-        int(config["n_vectors"]), int(spec["dim"]), rng,
-        n_clusters=int(spec["clusters"]))
-
-
-# -- labels ------------------------------------------------------------------
-
-def class_probs(spec: dict) -> np.ndarray:
-    """Probability of each label class.  ``spec``: {"kind": "uniform" |
-    "zipf", "classes": C, "alpha": a}; Zipf gives class c mass
-    1/(c+1)^alpha (alpha 1.0 over 10 classes: top 34%, rarest 3.4%)."""
-    c = int(spec["classes"])
-    if spec["kind"] == "uniform":
-        return np.full(c, 1.0 / c)
-    if spec["kind"] == "zipf":
-        w = 1.0 / np.arange(1, c + 1) ** float(spec["alpha"])
-        return w / w.sum()
-    raise ValueError(f"unknown label kind {spec['kind']!r}")
-
-
-def labels(spec: dict, n: int, rng: np.random.Generator) -> np.ndarray:
-    """One label per record, drawn from ``class_probs(spec)``."""
-    if spec["kind"] == "uniform":
-        return rng.integers(0, int(spec["classes"]), size=n).astype(np.int32)
-    return rng.choice(int(spec["classes"]), size=n,
-                      p=class_probs(spec)).astype(np.int32)
+def corpus(config: dict, rng: np.random.Generator, root: str = specm.ROOT) -> np.ndarray:
+    """The configuration's ``n_vectors`` records: ``make(n, dim, rng,
+    **params)`` of the generator its ``corpus`` group names, with the
+    group's other keys as ``params``."""
+    spec = dict(config["corpus"])
+    make = specm.plugin(root, "corpora", spec.pop("generator"), "corpus generator").make
+    return make(int(config["n_vectors"]), int(spec.pop("dim")), rng, **spec)
 
 
 # -- requests ----------------------------------------------------------------
@@ -109,12 +75,3 @@ def queries(spec: dict, corpus_: np.ndarray, n: int,
     picks = rng.choice(corpus_.shape[0], size=n, replace=False)
     q = corpus_[picks] + rng.normal(0.0, scale, size=(n, corpus_.shape[1]))
     return q.astype(np.float32)
-
-
-def request_labels(spec: dict, n_classes: int, n: int,
-                   rng: np.random.Generator) -> np.ndarray:
-    """The label each request filters on.  ``spec``: {"kind": "uniform"}
-    over the configuration's classes."""
-    if spec["kind"] != "uniform":
-        raise ValueError(f"unknown request-label kind {spec['kind']!r}")
-    return rng.integers(0, n_classes, size=n).astype(np.int32)

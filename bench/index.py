@@ -8,9 +8,10 @@ insertions, then ``GateANNEngine.build`` over that graph) and saves it under
 engine made by ``GateANNEngine.load`` (bit-identical to the built one).
 
 The cache key holds everything the saved bytes depend on: the
-configuration's data and index groups and its ``data_seed``, a hash of
-the build code, the matmul precision in force, the JAX version and the
-platform.
+configuration's data and index groups and its ``data_seed``, what its
+filter schema adds (``key_groups``: the groups its attributes come from,
+beyond ``labels``), a hash of the build code, the matmul precision in
+force, the JAX version and the platform.
 The cache is bounded: the least recently used files go first.
 """
 from __future__ import annotations
@@ -46,12 +47,13 @@ def code_hash(root: str = REPO) -> str:
 
 
 def cache_key(config: dict, code: str, precision: str, jax_version: str,
-              platform: str) -> str:
+              platform: str, schema_groups: dict | None = None) -> str:
     blob = json.dumps({
         "n_vectors": config["n_vectors"], "corpus": config["corpus"],
-        "labels": config["labels"], "index": config["index"],
+        "labels": config.get("labels"), "index": config["index"],
         "data_seed": int(config["data_seed"]), "code": code,
         "precision": precision, "jax": jax_version, "platform": platform,
+        **(schema_groups or {}),
     }, sort_keys=True)
     return hashlib.sha256(blob.encode()).hexdigest()[:24]
 
@@ -68,9 +70,10 @@ def _evict(index_dir: str, keep: str, limit: int = CACHE_BYTES) -> None:
             os.remove(f)
 
 
-def open_engine(config: dict, corpus: np.ndarray, labels: np.ndarray, *,
+def open_engine(config: dict, corpus: np.ndarray, attrs, schema, *,
                 cache_dir: str, say):
-    """The served engine of ``config``: (engine, info).
+    """The served engine of ``config``, whose records carry ``attrs`` of
+    the filter ``schema``: (engine, info).
 
     ``info`` says whether this run built the index (``cold``) and, if it
     did, how long the build took."""
@@ -81,7 +84,8 @@ def open_engine(config: dict, corpus: np.ndarray, labels: np.ndarray, *,
 
     key = cache_key(config, code_hash(),
                     str(jax.config.jax_default_matmul_precision),
-                    jax.__version__, jax.devices()[0].platform)
+                    jax.__version__, jax.devices()[0].platform,
+                    schema.key_groups(config))
     index_dir = os.path.join(cache_dir, "index")
     os.makedirs(index_dir, exist_ok=True)
     path = os.path.join(index_dir, f"{key}.gann")
@@ -93,7 +97,8 @@ def open_engine(config: dict, corpus: np.ndarray, labels: np.ndarray, *,
         graph = graphm.build_vamana(
             corpus, degree=ix["degree"], build_l=ix["build_l"],
             alpha=ix["alpha"], batch_size=ix["build_batch"], seed=bseed)
-        built = GateANNEngine.build(corpus, labels=labels, graph=graph,
+        built = GateANNEngine.build(corpus, graph=graph,
+                                    **schema.build_args(config, attrs),
                                     config=EngineConfig(
             degree=ix["degree"], build_l=ix["build_l"], alpha=ix["alpha"],
             pq_chunks=ix["pq_chunks"], r_max=ix["r_max"], seed=bseed))

@@ -45,9 +45,9 @@ def readings(name: str, seeds: list, seconds: float, *, root: str = runm.ROOT,
     try:
         for seed in seeds:
             t0 = time.perf_counter()
-            reqs, _calls, _box, vecs, q_labels = runm.window(s, seed, seconds, None)
-            out = runm.check_answers(reqs, vecs, q_labels, s.corpus, s.labels,
-                                     cfg, tr, s.info["path"],
+            reqs, _calls, _box, vecs, preds = runm.window(s, seed, seconds, None)
+            out = runm.check_answers(reqs, vecs, preds, s.corpus, s.attrs,
+                                     s.schema.passes, cfg, tr, s.info["path"],
                                      datam.streams(seed)["sample"], readings=True)
             out = dict(seed=seed, attempted=len(reqs),
                        seconds=time.perf_counter() - t0, **out)

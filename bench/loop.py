@@ -1,24 +1,33 @@
-"""The load generator: drives ``ServeFrontend.submit`` for a timed window.
+"""The load generator: drives the frontend for a timed window.
 
-The traffic file's ``loop.kind`` names the loop; ``closed`` is the one
-loop so far: ``clients`` threads, each with one request in flight, so a
-client sends its next request the moment the last one is answered
-(callers that each wait for a reply).  Latency runs from submit to
-answer.
+The traffic file's ``loop.kind`` names the loop, a file of its own,
+``bench/loops/<kind>.py``, whose ``run(spec, send, n, *, start, seconds,
+grace_s, rng, queue_depth)`` returns ``(requests, report)``:
+
+  * ``spec``: the traffic file's ``loop`` group;
+  * ``send(i)``: submits request ``i`` of the pool (``0 <= i < n``) to
+    the frontend and returns its handle, whose ``result(timeout)`` gives
+    the answer and whose ``trace`` is the frontend's ``RequestTrace``;
+  * ``rng``: the run's ``arrivals`` stream; ``queue_depth()``: the
+    requests waiting in the frontend's queue;
+  * ``requests``: a ``Request`` for every request sent in the window;
+    ``report``: what the loop tells about itself (how late it sent).
 
 Requests are sent from ``start`` until ``start + seconds``.  Every
 request sent in the window is then waited for (up to ``grace_s``), and
 counts: the window's throughput is all its requests over the time from
 ``start`` to the last answer, and a request that fails or never comes
-back counts at an infinite latency.
+back counts at an infinite latency.  Latency runs from the time a
+request was due (``Request.t_sched``).
 """
 from __future__ import annotations
 
 import dataclasses
-import threading
 import time
 
 import numpy as np
+
+from bench import spec as specm
 
 
 @dataclasses.dataclass
@@ -40,13 +49,20 @@ class Request:
         return self.t_done - self.t_sched if self.ok else float("inf")
 
 
-def _serve_one(srv, req: Request, tenant: str, vec, timeout: float) -> None:
+def submit(send, req: Request):
+    """Send ``req``; its handle, or None where the frontend refused it."""
+    req.t_submit = time.perf_counter()
     try:
-        h = srv.submit(tenant, vec, timeout=timeout)
+        h = send(req.index)
     except Exception as e:  # noqa: BLE001 — a refused request is a result
         req.error = f"refused: {e!r}"
-        return
+        return None
     req.trace = h.trace
+    return h
+
+
+def wait(h, req: Request, timeout: float) -> None:
+    """Wait for ``req``'s answer; stamp when it came."""
     try:
         req.ids = h.result(timeout=timeout)
     except Exception as e:  # noqa: BLE001 — a failed request is a result
@@ -54,54 +70,15 @@ def _serve_one(srv, req: Request, tenant: str, vec, timeout: float) -> None:
     req.t_done = time.perf_counter()
 
 
-def closed(srv, tenants, vecs, *, clients: int, start: float, seconds: float,
-           grace_s: float) -> tuple[list[Request], dict]:
-    """``tenants[i]``, ``vecs[i]``: request i of the pool, sent in order
-    (cyclically) by whichever client is free."""
-    end = start + seconds
-    lock = threading.Lock()
-    out: list[Request] = []
-    gaps: list[float] = []  # answer -> next submit, per client (generator lag)
-    cursor = [0]
-
-    def client():
-        last = None
-        while True:
-            now = time.perf_counter()
-            if now >= end:
-                return
-            with lock:
-                i = cursor[0] % len(vecs)
-                cursor[0] += 1
-                req = Request(index=i, t_sched=now)
-                out.append(req)
-            if last is not None:
-                gaps.append(now - last)
-            req.t_submit = now
-            _serve_one(srv, req, tenants[i], vecs[i], seconds + grace_s)
-            last = req.t_done
-            if not req.ok:
-                last = None
-
-    while time.perf_counter() < start:
-        time.sleep(min(1e-3, max(0.0, start - time.perf_counter())))
-    threads = [threading.Thread(target=client, name=f"client-{c}", daemon=True)
-               for c in range(clients)]
-    for t in threads:
-        t.start()
-    for t in threads:
-        t.join(timeout=seconds + 2 * grace_s)
-    g = np.asarray(gaps) if gaps else np.zeros(1)
-    return out, {"loop": "closed", "clients": clients,
-                 "answer_to_submit_mean_s": float(g.mean()),
-                 "answer_to_submit_max_s": float(g.max()),
-                 "threads_left": sum(t.is_alive() for t in threads)}
+def sleep_until(t: float) -> None:
+    while (left := t - time.perf_counter()) > 0:
+        time.sleep(min(left, 1e-3) if left < 2e-3 else left - 1e-3)
 
 
-def run(spec: dict, srv, tenants, vecs, *, start: float, seconds: float,
-        grace_s: float) -> tuple[list[Request], dict]:
+def run(spec: dict, send, n: int, *, start: float, seconds: float, grace_s: float,
+        rng: np.random.Generator, queue_depth, root: str = specm.ROOT
+        ) -> tuple[list[Request], dict]:
     """The traffic file's loop (``spec``: its ``loop`` group)."""
-    if spec["kind"] != "closed":
-        raise ValueError(f"unknown loop kind {spec['kind']!r}")
-    return closed(srv, tenants, vecs, clients=int(spec["clients"]), start=start,
-                  seconds=seconds, grace_s=grace_s)
+    loop = specm.plugin(root, "loops", spec["kind"], "loop kind")
+    return loop.run(spec, send, n, start=start, seconds=seconds, grace_s=grace_s,
+                    rng=rng, queue_depth=queue_depth)

@@ -38,7 +38,6 @@ def _bf16(x: np.ndarray) -> np.ndarray:
 @dataclasses.dataclass(frozen=True)
 class Index:
     vectors: np.ndarray  # (N, D) float32 corpus records
-    labels: np.ndarray  # (N,) int label per record
     books: np.ndarray  # (C, Kc, D/C) PQ codebooks
     codes: np.ndarray  # (N, C) PQ code per chunk
     neighbors: np.ndarray  # (N, R) adjacency rows, -1 padded
@@ -87,14 +86,14 @@ def _masks(mode: str, sel: np.ndarray, passes: np.ndarray, entry: int):
     raise ValueError(f"unknown mode {mode!r}")
 
 
-def search(index: Index, q: np.ndarray, label: int, *, mode: str, L: int,
-           W: int, K: int, max_hops: int = 512,
+def search(index: Index, q: np.ndarray, passes_all: np.ndarray, *, mode: str,
+           L: int, W: int, K: int, max_hops: int = 512,
            precision: str = "f32") -> tuple[np.ndarray, np.ndarray]:
-    """Top-``K`` ids (-1 padded) and their distances for one query
-    filtered on ``label`` (``mode="unfiltered"`` ignores it)."""
+    """Top-``K`` ids (-1 padded) and their distances for one query whose
+    predicate the records of the (N,) bool mask ``passes_all`` pass (the
+    filter schema's ``passes``; ``mode="unfiltered"`` ignores it)."""
     if precision not in PRECISIONS:
         raise ValueError(f"precision {precision!r} not in {PRECISIONS}")
-    passes_all = index.labels == label
     lut = _lut(index, q, precision)
     n = index.vectors.shape[0]
     entry = int(index.entry)

@@ -9,13 +9,15 @@ order:
 
   1. the chip: JAX must see a TPU and as many chips as the cell asks
      for, or the run exits non-zero and prints no result;
-  2. set-up: the corpus and labels from the configuration's
-     ``data_seed``, the request pool from ``--seed``; the index loaded
-     from ``bench/.cache/index`` or built there first
-     (``bench/index.py``); the serving stack (``RAGServer`` behind
-     ``ServeFrontend``, one tenant per label); one engine call per
-     warm-up burst of the traffic file, so the window compiles nothing;
-  3. the window: the traffic file's loop (``bench/loop.py``) for
+  2. set-up: the corpus (``bench/corpora/``) and the records' attributes
+     (the configuration's filter schema, ``bench/filters/``) from the
+     configuration's ``data_seed``, the request pool (query vectors and
+     predicates) from ``--seed``; the index loaded from
+     ``bench/.cache/index`` or built there first (``bench/index.py``);
+     the serving stack (``RAGServer`` behind ``ServeFrontend``, with the
+     schema's tenants); one engine call per warm-up burst of the traffic
+     file, so the window compiles nothing;
+  3. the window: the traffic file's loop (``bench/loops/``) for
      ``--seconds``; with ``--trace 1`` a profiler trace of part of it;
   4. the check (``bench/check.py``) of a sample of the window's answers
      against the plain reference, after the program's state is freed;
@@ -112,11 +114,12 @@ class Run:
 
 class Stack:
     """The serving path of a configuration: ``RAGServer`` behind
-    ``ServeFrontend``, one tenant per label, with a thin wrapper around
-    ``RAGServer.retrieve`` that logs each engine call and marks it in a
-    profiler trace."""
+    ``ServeFrontend`` with the filter schema's tenants, and a thin wrapper
+    around ``RAGServer.retrieve`` that logs each engine call and marks it
+    in a profiler trace."""
 
-    def __init__(self, engine, config: dict, traffic: dict, n: int, timeout: float):
+    def __init__(self, engine, config: dict, traffic: dict, n: int, timeout: float,
+                 schema):
         import jax
 
         from repro.core import SearchConfig
@@ -144,20 +147,20 @@ class Stack:
             return ids, stats
 
         self.rag.retrieve = retrieve
-        classes = int(config["labels"]["classes"])
-        self.tenants = [f"label{c}" for c in range(classes)]
+        self.config, self.schema = config, schema
+        tenants = schema.tenants(config)
+        self.filters = {name: (kind, params) for name, kind, params in tenants}
         self.frontend = ServeFrontend(
             self.rag,
-            [TenantSpec(t, "label", np.int32(c),
+            [TenantSpec(name, kind, params,
                         max_inflight=int(traffic.get("max_inflight", 4096)))
-             for c, t in enumerate(self.tenants)],
+             for name, kind, params in tenants],
             max_batch=int(traffic["max_batch"]),
             batch_window_s=float(traffic["batch_window_s"]),
             admission_timeout_s=timeout,
         )
 
-    def warm(self, vecs: np.ndarray, labels: np.ndarray, bursts,
-             max_batch: int) -> None:
+    def warm(self, vecs: np.ndarray, predicates, bursts, max_batch: int) -> None:
         """One engine call per burst size, straight into ``retrieve`` so
         each compiles exactly its bucket's shapes; then every batch size
         of the frontend once more through ``retrieve`` over a stand-in
@@ -165,11 +168,13 @@ class Stack:
         that size without searching."""
         from repro.serve.rag import RAGRequest
 
-        reqs = [RAGRequest(query_vec=vecs[i % len(vecs)],
-                           prompt_tokens=np.zeros((0,), np.int32),
-                           filter_kind="label",
-                           filter_params=np.int32(labels[i % len(vecs)]))
-                for i in range(max(max_batch, sum(bursts)))]
+        reqs = []
+        for i in range(max(max_batch, sum(bursts))):
+            tenant, _ = self.schema.route(self.config, predicates[i % len(vecs)])
+            kind, params = self.filters[tenant]
+            reqs.append(RAGRequest(query_vec=vecs[i % len(vecs)],
+                                   prompt_tokens=np.zeros((0,), np.int32),
+                                   filter_kind=kind, filter_params=params))
         j = 0
         for b in bursts:
             self.rag.retrieve(reqs[j:j + int(b)])
@@ -238,9 +243,11 @@ def _traced_window(start: float, seconds: float, log_dir: str, box: dict) -> thr
         opts.host_tracer_level = 2
         obs.enable()
         obs.trace.enable()
+        # the part starts once the profiler collects: its start can take
+        # a second or more, and the work and spans are counted from here
+        jax.profiler.start_trace(log_dir, profiler_options=opts)
         box["spans0"] = _span_sums()
         box["t0"] = time.perf_counter()
-        jax.profiler.start_trace(log_dir, profiler_options=opts)
         time.sleep(max(0.0, box["t0"] + length - time.perf_counter()))
         box["t1"] = time.perf_counter()
         box["spans1"] = _span_sums()
@@ -269,10 +276,11 @@ def _pro_rated_work(calls: list, t0: float, t1: float, config: dict) -> dict:
     return {"ops": ops, "bytes": nbytes}
 
 
-def _window(stack: Stack, tr: dict, tenants: list, vecs: np.ndarray,
-            seconds: float, trace_dir: str | None):
-    """Drive the traffic for ``seconds``: (requests, generator report,
-    trace box).  Counts the programs compiled while it runs."""
+def _window(stack: Stack, tr: dict, send, n: int, seconds: float,
+            trace_dir: str | None, rng, root: str):
+    """Drive the traffic for ``seconds`` (``send(i)`` submits request ``i``
+    of the pool of ``n``): (requests, trace box).  Counts the programs
+    compiled while it runs."""
     import jax
 
     # the compile event fires for a program loaded from the persistent
@@ -291,8 +299,9 @@ def _window(stack: Stack, tr: dict, tenants: list, vecs: np.ndarray,
     box: dict = {"start": start}
     tracer = _traced_window(start, seconds, trace_dir, box) if trace_dir else None
     try:
-        reqs, gen = loopm.run(tr["loop"], stack.frontend, tenants, vecs,
-                              start=start, seconds=seconds, grace_s=GRACE_S)
+        reqs, gen = loopm.run(tr["loop"], send, n, start=start, seconds=seconds,
+                              grace_s=GRACE_S, rng=rng,
+                              queue_depth=stack.frontend.queue_depth, root=root)
         if tracer is not None:
             tracer.join()
     finally:
@@ -337,29 +346,29 @@ def _faults(n: int) -> dict:
                 served[:len(served) - len(served) // 2] + served[:len(served) // 2])}
 
 
-def check_answers(reqs: list, vecs: np.ndarray, q_labels: np.ndarray, corpus,
-                  labels, cfg: dict, tr: dict, index_path: str, rng,
+def check_answers(reqs: list, vecs: np.ndarray, predicates, corpus, attrs,
+                  passes, cfg: dict, tr: dict, index_path: str, rng,
                   readings: bool) -> dict:
     """``{"program": numbers}`` of a sample of the answers; with
-    ``readings`` also the control's numbers and those of each fault."""
+    ``readings`` also the control's numbers and those of each fault.
+    ``passes``: the filter schema's."""
     t0 = time.perf_counter()
     search = cfg["search"]
     k = int(search["result_k"])
-    ref_index = checkm.reference_index(index_path, corpus, labels,
-                                       cfg["index"]["r_max"])
+    ref_index = checkm.reference_index(index_path, corpus, cfg["index"]["r_max"])
     answered = [r for r in reqs if r.ok]
     size = min(int(tr["check_sample"]), len(answered))
     sample = [answered[i] for i in
               np.sort(rng.choice(len(answered), size=size, replace=False))]
     served = [r.ids for r in sample]
     rows = np.asarray([r.index for r in sample], np.int64)
-    s_vecs, s_labs = vecs[rows], q_labels[rows].astype(np.int64)
-    reference = checkm.answers(ref_index, s_vecs, s_labs, search)
-    truth = checkm.brute_force(corpus, labels, s_vecs, s_labs, k)
+    s_vecs, s_preds = vecs[rows], [predicates[i] for i in rows]
+    reference = checkm.answers(ref_index, s_vecs, attrs, s_preds, passes, search)
+    truth = checkm.brute_force(corpus, attrs, s_vecs, s_preds, k, passes)
 
     def numbers(answers_):
-        return checkm.numbers(answers_, reference, truth, corpus, labels,
-                              s_vecs, s_labs, k)
+        return checkm.numbers(answers_, reference, truth, corpus, attrs,
+                              s_vecs, s_preds, passes, k)
 
     out = {"program": dict(numbers(served), unanswered=len(reqs) - len(answered),
                            sample=size)}
@@ -367,8 +376,8 @@ def check_answers(reqs: list, vecs: np.ndarray, q_labels: np.ndarray, corpus,
         f"recall@{k} against exact filtered brute force "
         f"{1.0 - out['program']['recall_miss']:.4f}")
     if readings:
-        out["control"] = numbers(checkm.answers(ref_index, s_vecs, s_labs, search,
-                                                precision="bf16"))
+        out["control"] = numbers(checkm.answers(ref_index, s_vecs, attrs, s_preds,
+                                                passes, search, precision="bf16"))
         out["faults"] = {name: numbers(f(list(served)))
                          for name, f in _faults(corpus.shape[0]).items()}
     return out
@@ -381,21 +390,20 @@ class Setup:
     cell: specm.Cell
     bench: specm.Bench
     corpus: np.ndarray
-    labels: np.ndarray
+    attrs: object  # the records' attributes, by the filter schema
+    schema: object  # the configuration's filter schema (bench/filters/)
     stack: Stack
     info: dict  # bench/index.py: cold, path, build_s
     setup_s: float
 
 
-def requests(cell: specm.Cell, corpus: np.ndarray, seed: int):
-    """The seed's request pool: (query vectors, the label each filters on)."""
+def requests(cell: specm.Cell, corpus: np.ndarray, seed: int, schema):
+    """The seed's request pool: (query vectors, each one's predicate)."""
     cfg, tr = cell.config, cell.traffic
     rng = datam.streams(seed)
     pool = min(int(tr["pool"]), corpus.shape[0])
     vecs = datam.queries(tr["query"], corpus, pool, rng["queries"])
-    q_labels = datam.request_labels(tr["labels"], int(cfg["labels"]["classes"]),
-                                    pool, rng["requests"])
-    return vecs, q_labels
+    return vecs, schema.requests(cfg, tr["labels"], pool, rng["requests"])
 
 
 def set_up(name: str, seed: int, seconds: float, *, root: str, cache_dir: str,
@@ -405,29 +413,37 @@ def set_up(name: str, seed: int, seconds: float, *, root: str, cache_dir: str,
     bench = specm.Bench(root)
     cell = bench.cell(name)
     cfg, tr = cell.config, cell.traffic
+    schema = bench.schema(cfg)
     data_rng = datam.streams(cfg["data_seed"])
-    corpus = datam.corpus(cfg, data_rng["corpus"])
-    labels = datam.labels(cfg["labels"], corpus.shape[0], data_rng["labels"])
-    vecs, q_labels = requests(cell, corpus, seed)
-    engine, info = indexm.open_engine(cfg, corpus, labels,
+    corpus = datam.corpus(cfg, data_rng["corpus"], root)
+    attrs = schema.records(cfg, corpus.shape[0], data_rng["labels"])
+    vecs, preds = requests(cell, corpus, seed, schema)
+    engine, info = indexm.open_engine(cfg, corpus, attrs, schema,
                                       cache_dir=cache_dir, say=say)
-    stack = Stack(engine, cfg, tr, corpus.shape[0], seconds + GRACE_S)
+    stack = Stack(engine, cfg, tr, corpus.shape[0], seconds + GRACE_S, schema)
     del engine
-    stack.warm(vecs[::-1], q_labels[::-1], tr["warmup_bursts"], int(tr["max_batch"]))
+    stack.warm(vecs[::-1], preds[::-1], tr["warmup_bursts"], int(tr["max_batch"]))
     stack.calls.clear()
     setup_s = time.perf_counter() - start_time
     say(f"set-up {setup_s:.3f} s "
         f"({'cold: index built' if info['cold'] else 'warm: index loaded'})")
-    return Setup(cell, bench, corpus, labels, stack, info, setup_s)
+    return Setup(cell, bench, corpus, attrs, schema, stack, info, setup_s)
 
 
 def window(s: Setup, seed: int, seconds: float, trace_dir: str | None):
     """One window of the seed's traffic on a set-up stack: (requests,
-    engine calls, trace box, request vectors, their labels)."""
-    vecs, q_labels = requests(s.cell, s.corpus, seed)
+    engine calls, trace box, request vectors, their predicates)."""
+    vecs, preds = requests(s.cell, s.corpus, seed, s.schema)
+    routes = [s.schema.route(s.cell.config, p) for p in preds]
+    submit, timeout = s.stack.frontend.submit, seconds + GRACE_S
+
+    def send(i):
+        tenant, kw = routes[i]
+        return submit(tenant, vecs[i], timeout=timeout, **kw)
+
     s.stack.calls.clear()
-    reqs, box = _window(s.stack, s.cell.traffic, [s.stack.tenants[c] for c in q_labels],
-                        vecs, seconds, trace_dir)
+    reqs, box = _window(s.stack, s.cell.traffic, send, len(vecs), seconds, trace_dir,
+                        datam.streams(seed)["arrivals"], s.bench.root)
     calls = [c for c in s.stack.calls if c.t0 >= box["start"]]
     if calls:
         dur = np.asarray([c.t1 - c.t0 for c in calls])
@@ -435,7 +451,7 @@ def window(s: Setup, seed: int, seconds: float, trace_dir: str | None):
             f"median {np.median(dur):.4f} max {dur.max():.4f}; rows mean "
             f"{np.mean([c.rows for c in calls]):.2f}; rounds mean "
             f"{np.mean([c.stats['n_hops'].max() for c in calls]):.2f}")
-    return reqs, calls, box, vecs, q_labels
+    return reqs, calls, box, vecs, preds
 
 
 def run_cell(name: str, seed: int, seconds: float, trace: bool, *, devices,
@@ -450,7 +466,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *, devices,
                start_time=START if start_time is None else start_time)
     cfg, tr = s.cell.config, s.cell.traffic
     trace_dir = tempfile.mkdtemp(prefix="bench-trace-") if trace else None
-    reqs, calls, box, vecs, q_labels = window(s, seed, seconds, trace_dir)
+    reqs, calls, box, vecs, preds = window(s, seed, seconds, trace_dir)
     peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0) for d in devices)
     say(f"memory_peak_bytes {peak}")
     s.stack.close()
@@ -469,9 +485,9 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *, devices,
     say("metrics " + json.dumps(metrics))
 
     # the check, on the host, once the program's state is freed
-    numbers = check_answers(reqs, vecs, q_labels, s.corpus, s.labels, cfg, tr,
-                     s.info["path"], datam.streams(seed)["sample"],
-                     readings=False)["program"]
+    numbers = check_answers(reqs, vecs, preds, s.corpus, s.attrs, s.schema.passes,
+                            cfg, tr, s.info["path"], datam.streams(seed)["sample"],
+                            readings=False)["program"]
     say("numbers " + json.dumps(numbers))
     limits = cfg["limits"]
     device = {"platform": devices[0].platform, "kind": kind,

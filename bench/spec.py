@@ -8,6 +8,12 @@ configuration, a mix or a metric by adding files and entries only:
     <file of the configs entry>            one configuration (JSON)
     <root>/bench/traffic/<traffic>.json    one traffic mix
     <root>/bench/metrics/<metric>.py       one reader: read(run) -> float | None
+
+and below them, each named by a configuration or traffic file:
+
+    <root>/bench/corpora/<generator>.py    one corpus generator (``corpus.generator``)
+    <root>/bench/filters/<schema>.py       one filter schema (``filter``)
+    <root>/bench/loops/<kind>.py           one arrival loop (``loop.kind``)
 """
 from __future__ import annotations
 
@@ -18,6 +24,19 @@ import os
 from typing import Callable
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def plugin(root: str, folder: str, name: str, what: str):
+    """The module ``<root>/bench/<folder>/<name>.py``; a name with no
+    such file is refused as an unknown ``what``."""
+    path = os.path.join(root, "bench", folder, f"{name}.py")
+    if os.path.basename(name) != name or not os.path.isfile(path):
+        raise ValueError(f"unknown {what} {name!r}: no file bench/{folder}/{name}.py")
+    spec = importlib.util.spec_from_file_location(
+        f"bench_{folder}_" + name.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 @dataclasses.dataclass(frozen=True)
@@ -77,11 +96,8 @@ class Bench:
 
     def reader(self, metric: str) -> Callable:
         """``read`` of ``bench/metrics/<metric>.py``."""
-        path = os.path.join(self.root, "bench", "metrics", f"{metric}.py")
-        spec = importlib.util.spec_from_file_location(
-            "bench_metric_" + metric.replace(".", "_").replace("-", "_"), path)
-        if spec is None:
-            raise FileNotFoundError(path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod.read
+        return plugin(self.root, "metrics", metric, "metric").read
+
+    def schema(self, config: dict):
+        """The filter schema of a configuration (``bench/filters/``)."""
+        return plugin(self.root, "filters", config["filter"], "filter schema")

@@ -11,8 +11,10 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 sys.path[:0] = [REPO, os.path.join(REPO, "src")]
 
-from bench import check, data  # noqa: E402
+from bench import check, data, spec  # noqa: E402
 from bench.reference import index_file, oracle  # noqa: E402
+
+LABEL = spec.Bench().schema({"filter": "label"})
 
 N, DIM, CLASSES = 1500, 32, 4
 SEARCH = {"mode": "gate", "search_l": 24, "beam_width": 4, "result_k": 10,
@@ -23,16 +25,18 @@ SEARCH = {"mode": "gate", "search_l": 24, "beam_width": 4, "result_k": 10,
 def index(tmp_path_factory):
     from repro.core import EngineConfig, GateANNEngine
     rng = data.streams(2**40 + 11)
-    corpus = data.bigann_like(N, DIM, rng["corpus"], n_clusters=8)
-    labels = data.labels({"kind": "uniform", "classes": CLASSES}, N, rng["labels"])
+    cfg = {"n_vectors": N, "corpus": {"generator": "bigann_like", "dim": DIM, "clusters": 8},
+           "labels": {"kind": "uniform", "classes": CLASSES}}
+    corpus = data.corpus(cfg, rng["corpus"])
+    labels = LABEL.records(cfg, N, rng["labels"])
     queries = data.queries({"kind": "near_corpus", "noise": 0.05}, corpus, 24,
                            rng["queries"])
-    q_labels = data.request_labels({"kind": "uniform"}, CLASSES, 24, rng["requests"])
+    q_labels = LABEL.requests(cfg, {"kind": "uniform"}, 24, rng["requests"])
     path = str(tmp_path_factory.mktemp("ref") / "index.gann")
-    GateANNEngine.build(corpus, labels=labels, config=EngineConfig(
+    GateANNEngine.build(corpus, **LABEL.build_args(cfg, labels), config=EngineConfig(
         degree=12, build_l=24, pq_chunks=8, r_max=6, seed=3)).save(path)
-    ref = check.reference_index(path, corpus, labels, r_max=6)
-    return path, ref, queries, q_labels
+    ref = check.reference_index(path, corpus, r_max=6)
+    return path, ref, labels, queries, q_labels
 
 
 @pytest.mark.parametrize("part", ["neighbors", "pq_books", "pq_codes", "medoid"])
@@ -52,7 +56,7 @@ def test_reference_agrees_with_engine_search(index, tier, depth):
 
     from repro.core import GateANNEngine, SearchConfig
 
-    path, ref, queries, q_labels = index
+    path, ref, labels, queries, q_labels = index
     eng = GateANNEngine.load(path, store_tier=tier)
     try:
         out = eng.search(queries, filter_kind="label",
@@ -64,24 +68,25 @@ def test_reference_agrees_with_engine_search(index, tier, depth):
     finally:
         if eng.measured_store() is not None:
             eng.measured_store().close()
-    want = np.stack([oracle.search(ref, q, int(lab), mode="gate", L=24, W=4, K=10)[0]
+    want = np.stack([oracle.search(ref, q, LABEL.passes(labels, lab), mode="gate",
+                                   L=24, W=4, K=10)[0]
                      for q, lab in zip(queries, q_labels)])
     np.testing.assert_array_equal(got, want)
-    truth = check.brute_force(ref.vectors, ref.labels, queries, q_labels, 10)
-    numbers = check.numbers(list(got), list(want), truth, ref.vectors, ref.labels,
-                            queries, q_labels, 10)
+    truth = check.brute_force(ref.vectors, labels, queries, q_labels, 10, LABEL.passes)
+    numbers = check.numbers(list(got), list(want), truth, ref.vectors, labels,
+                            queries, q_labels, LABEL.passes, 10)
     assert numbers["mismatch"] == 0.0 and numbers["filter_violations"] == 0
     assert numbers["order_errors"] == 0
 
 
 def test_control_in_bfloat16_fails_the_mismatch_limit(index):
-    from bench import spec
-
-    _, ref, queries, q_labels = index
-    served = check.answers(ref, queries, q_labels, SEARCH)
-    truth = check.brute_force(ref.vectors, ref.labels, queries, q_labels, 10)
-    control = check.numbers(check.answers(ref, queries, q_labels, SEARCH, precision="bf16"),
-                            served, truth, ref.vectors, ref.labels, queries, q_labels, 10)
+    _, ref, labels, queries, q_labels = index
+    passes = LABEL.passes
+    served = check.answers(ref, queries, labels, q_labels, passes, SEARCH)
+    truth = check.brute_force(ref.vectors, labels, queries, q_labels, 10, passes)
+    control = check.numbers(check.answers(ref, queries, labels, q_labels, passes, SEARCH,
+                                          precision="bf16"),
+                            served, truth, ref.vectors, labels, queries, q_labels, passes, 10)
     bench = spec.Bench()
     limit = max(bench.config(c["name"])["limits"]["mismatch"]
                 for c in bench.spec["configs"])

@@ -1,6 +1,6 @@
 """A benchmark root of tiny cells for the CPU tests: the repository's
-traffic mixes and metric readers, with configurations small enough to
-build in seconds."""
+traffic mixes, metric readers, corpus generators, filter schemas and
+loops, with configurations small enough to build in seconds."""
 from __future__ import annotations
 
 import json
@@ -19,6 +19,7 @@ def config(tier: str) -> dict:
         "n_vectors": 1200,
         "element_dtype": "float32",
         "corpus": {"generator": "bigann_like", "dim": 16, "clusters": 8},
+        "filter": "label",
         "labels": {"kind": "uniform", "classes": 4},
         "index": {"degree": 12, "build_l": 24, "alpha": 1.2, "build_batch": 512, "pq_chunks": 4,
                   "r_max": 6},
@@ -45,22 +46,30 @@ TRAFFIC = {
     "warmup_bursts": [4, 8, 8, 8],
     "check_sample": 48,
 }
+# the same requests arriving on their own schedule, below the tiny
+# cells' capacity on a CPU
+OPEN_TRAFFIC = dict(TRAFFIC, loop={"kind": "open", "rate_qps": 40.0})
+
+
+# each of the repository's cells and the tiny cell that reports its metrics
+STANDS_FOR = {"bigann-ssd.closed64": "tiny-disk.t", "deep-hbm.closed64": "tiny-memory.t",
+              "bigann-ssd.open80": "tiny-disk.o"}
 
 
 def make_root(path: str) -> str:
-    """``path`` made into a benchmark root holding cells ``tiny-disk.t``
-    and ``tiny-memory.t`` beside the repository's own entries."""
+    """``path`` made into a benchmark root holding cells ``tiny-disk.t``,
+    ``tiny-memory.t`` and ``tiny-disk.o`` (the open loop) beside the
+    repository's own entries; each tiny cell is listed where the cell it
+    stands for is (``STANDS_FOR``)."""
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         spec = json.load(f)
     bench = os.path.join(path, "bench")
-    shutil.copytree(os.path.join(REPO, "bench", "metrics"),
-                    os.path.join(bench, "metrics"))
-    shutil.copytree(os.path.join(REPO, "bench", "traffic"),
-                    os.path.join(bench, "traffic"))
-    shutil.copytree(os.path.join(REPO, "bench", "configs"),
-                    os.path.join(bench, "configs"))
-    with open(os.path.join(bench, "traffic", "t.json"), "w") as f:
-        json.dump(TRAFFIC, f)
+    for folder in ("metrics", "traffic", "configs", "corpora", "filters", "loops"):
+        shutil.copytree(os.path.join(REPO, "bench", folder), os.path.join(bench, folder),
+                        ignore=shutil.ignore_patterns("__pycache__"))
+    for name, traffic in (("t", TRAFFIC), ("o", OPEN_TRAFFIC)):
+        with open(os.path.join(bench, "traffic", f"{name}.json"), "w") as f:
+            json.dump(traffic, f)
     for tier in ("disk", "memory"):
         cfg = config(tier)
         rel = f"bench/configs/{cfg['name']}.json"
@@ -70,9 +79,11 @@ def make_root(path: str) -> str:
                                 "file": rel, "reduced": [], "why": "tests"})
         spec["workloads"].append({"name": f"{cfg['name']}.t", "config": cfg["name"],
                                   "traffic": "t", "chips": 1, "why": "tests"})
-    for m in spec["per_layer"]:
+    spec["workloads"].append({"name": "tiny-disk.o", "config": "tiny-disk",
+                              "traffic": "o", "chips": 1, "why": "tests"})
+    for m in spec["end_to_end"] + spec["per_layer"]:
         if "workloads" in m:
-            m["workloads"].append("tiny-disk.t")
+            m["workloads"] += [t for real, t in STANDS_FOR.items() if real in m["workloads"]]
     with open(os.path.join(path, "BENCHMARK.json"), "w") as f:
         json.dump(spec, f, indent=1)
     return path
