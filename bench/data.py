@@ -65,13 +65,14 @@ def corpus(config: dict, rng: np.random.Generator, root: str = specm.ROOT) -> np
 # -- requests ----------------------------------------------------------------
 
 def queries(spec: dict, corpus_: np.ndarray, n: int,
-            rng: np.random.Generator) -> np.ndarray:
-    """``n`` query vectors.  ``spec``: {"kind": "near_corpus", "noise"}
-    draws distinct corpus points plus Gaussian noise of ``noise`` times
-    the corpus's mean absolute value."""
+            rng: np.random.Generator) -> tuple[np.ndarray, np.ndarray]:
+    """``n`` query vectors and the corpus row each was drawn near.
+    ``spec``: {"kind": "near_corpus", "noise"} draws distinct corpus
+    points plus Gaussian noise of ``noise`` times the corpus's mean
+    absolute value."""
     if spec["kind"] != "near_corpus":
         raise ValueError(f"unknown query kind {spec['kind']!r}")
     scale = np.abs(corpus_).mean() * float(spec["noise"])
     picks = rng.choice(corpus_.shape[0], size=n, replace=False)
     q = corpus_[picks] + rng.normal(0.0, scale, size=(n, corpus_.shape[1]))
-    return q.astype(np.float32)
+    return q.astype(np.float32), picks

@@ -3,7 +3,10 @@ one label (GateANN section 5.1).
 
 A schema file gives, for its configurations (those whose ``filter`` names
 it), every function below; ``passes`` alone is the reference's, and
-imports nothing of the program.
+imports nothing of the program.  ``records`` sees the corpus its
+attributes describe, and ``requests`` each query vector, the corpus row
+it was drawn near and the records' attributes; this schema draws from
+its stream alone and reads none of them.
 
 Record labels follow the configuration's ``labels`` group:
 {"kind": "uniform" | "zipf", "classes": C, "alpha": a}.  A request's
@@ -25,8 +28,10 @@ def class_probs(spec: dict, classes: int) -> np.ndarray:
     raise ValueError(f"unknown label kind {spec['kind']!r}")
 
 
-def records(config: dict, n: int, rng: np.random.Generator) -> np.ndarray:
-    """(n,) int32: each record's label, from the ``labels`` stream."""
+def records(config: dict, n: int, rng: np.random.Generator,
+            corpus: np.ndarray) -> np.ndarray:
+    """(n,) int32: each record's label, from the ``labels`` stream
+    (``corpus``: the (n, dim) records)."""
     spec = config["labels"]
     c = int(spec["classes"])
     if spec["kind"] == "uniform":
@@ -45,9 +50,12 @@ def key_groups(config: dict) -> dict:
     return {}
 
 
-def requests(config: dict, spec: dict, n: int, rng: np.random.Generator) -> np.ndarray:
+def requests(config: dict, spec: dict, n: int, rng: np.random.Generator,
+             queries: np.ndarray, sources: np.ndarray, attrs) -> np.ndarray:
     """(n,) int32: the label each request asks for, from the ``requests``
-    stream; ``spec`` is the traffic's ``labels`` group."""
+    stream; ``spec`` is the traffic's ``labels`` group (``queries``: the
+    (n, dim) query vectors; ``sources``: the corpus row each was drawn
+    near; ``attrs``: what ``records`` made)."""
     c = int(config["labels"]["classes"])
     if spec["kind"] == "uniform":
         return rng.integers(0, c, size=n).astype(np.int32)
@@ -61,7 +69,11 @@ def tenants(config: dict) -> list[tuple]:
 
 
 def route(config: dict, predicate) -> tuple[str, dict]:
-    """How one request is sent: its tenant and further ``submit`` keywords."""
+    """How one request is sent: its tenant and further ``submit``
+    keywords.  A keyword that carries a predicate has the name of a
+    ``RAGRequest`` field (``filter_kind``, ``filter_params``) and takes
+    the place of the tenant's own; the warm-up lays such keywords over
+    the tenant's filter, so it compiles what the window serves."""
     return f"label{int(predicate)}", {}
 
 

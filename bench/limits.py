@@ -10,8 +10,8 @@ the cell's own load, long enough to answer as many requests as a run
 checks, and the check of its sample.  Beside the program's numbers it
 prints, on the same sampled requests, those of
 
-  * the control: the reference computed in bfloat16 put in the
-    program's place;
+  * the control: the configuration's reference plan computed in
+    bfloat16 put in the program's place;
   * two faults planted in the program's answers: each answer's first id
     altered, and half of the sample given the other half's answers.
 
@@ -40,15 +40,13 @@ def readings(name: str, seeds: list, seconds: float, *, root: str = runm.ROOT,
     each fault's."""
     s = runm.set_up(name, seeds[0], seconds, root=root, cache_dir=cache_dir,
                     start_time=time.perf_counter())
-    cfg, tr = s.cell.config, s.cell.traffic
     rows = []
     try:
         for seed in seeds:
             t0 = time.perf_counter()
             reqs, _calls, _box, vecs, preds = runm.window(s, seed, seconds, None)
-            out = runm.check_answers(reqs, vecs, preds, s.corpus, s.attrs,
-                                     s.schema.passes, cfg, tr, s.info["path"],
-                                     datam.streams(seed)["sample"], readings=True)
+            out = runm.check_answers(s, reqs, vecs, preds, datam.streams(seed)["sample"],
+                                     readings=True)
             out = dict(seed=seed, attempted=len(reqs),
                        seconds=time.perf_counter() - t0, **out)
             rows.append(out)

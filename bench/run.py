@@ -12,7 +12,10 @@ order:
   2. set-up: the corpus (``bench/corpora/``) and the records' attributes
      (the configuration's filter schema, ``bench/filters/``) from the
      configuration's ``data_seed``, the request pool (query vectors and
-     predicates) from ``--seed``; the index loaded from
+     predicates) from ``--seed``; the engine's ``SearchConfig`` from the
+     whole ``search`` group and the reference plan
+     (``bench/reference/plans/``), both refused by name before any index
+     is built if unknown; the index loaded from
      ``bench/.cache/index`` or built there first (``bench/index.py``);
      the serving stack (``RAGServer`` behind ``ServeFrontend``, with the
      schema's tenants); one engine call per warm-up burst of the traffic
@@ -20,7 +23,8 @@ order:
   3. the window: the traffic file's loop (``bench/loops/``) for
      ``--seconds``; with ``--trace 1`` a profiler trace of part of it;
   4. the check (``bench/check.py``) of a sample of the window's answers
-     against the plain reference, after the program's state is freed;
+     against the configuration's reference plan, after the program's
+     state is freed;
   5. the result: info lines, then the numbers compared with their limits
      as the last lines on stderr, then one JSON line on stdout.
 
@@ -60,6 +64,7 @@ from bench import loop as loopm  # noqa: E402
 from bench import roofline  # noqa: E402
 from bench import spec as specm  # noqa: E402
 from bench import trace as tracem  # noqa: E402
+from bench.reference import index_file  # noqa: E402
 
 GRACE_S = 60.0  # how long past the window an answer may come back
 TRACE_DELAY_S = 1.0  # the traced part of a --trace 1 window starts here
@@ -119,20 +124,15 @@ class Stack:
     in a profiler trace."""
 
     def __init__(self, engine, config: dict, traffic: dict, n: int, timeout: float,
-                 schema):
+                 schema, search):
         import jax
 
-        from repro.core import SearchConfig
         from repro.serve import RAGServer, ServeFrontend, TenantSpec
 
-        s = config["search"]
         self.rag = RAGServer(
             engine=engine, cfg=None, params=None, layout=None,
             passage_tokens=np.zeros((n, 1), np.int32),
-            search_config=SearchConfig(
-                mode=s["mode"], search_l=s["search_l"], result_k=s["result_k"],
-                beam_width=s["beam_width"], max_hops=s["max_hops"],
-                pipeline_depth=s["pipeline_depth"]),
+            search_config=search,
             bucket_sizes=tuple(traffic["bucket_sizes"]),
         )
         self.calls: list[Call] = []
@@ -165,16 +165,22 @@ class Stack:
         each compiles exactly its bucket's shapes; then every batch size
         of the frontend once more through ``retrieve`` over a stand-in
         engine, which compiles the small ops that assemble a batch of
-        that size without searching."""
+        that size without searching.  Each request is built as the
+        frontend would serve it: the filter of the tenant that the
+        schema's ``route`` names, with the route's keywords that name a
+        ``RAGRequest`` field laid over it."""
         from repro.serve.rag import RAGRequest
 
+        fields = {f.name for f in dataclasses.fields(RAGRequest)}
         reqs = []
         for i in range(max(max_batch, sum(bursts))):
-            tenant, _ = self.schema.route(self.config, predicates[i % len(vecs)])
+            tenant, kw = self.schema.route(self.config, predicates[i % len(vecs)])
             kind, params = self.filters[tenant]
-            reqs.append(RAGRequest(query_vec=vecs[i % len(vecs)],
-                                   prompt_tokens=np.zeros((0,), np.int32),
-                                   filter_kind=kind, filter_params=params))
+            req = dict(query_vec=vecs[i % len(vecs)],
+                       prompt_tokens=np.zeros((0,), np.int32),
+                       filter_kind=kind, filter_params=params)
+            req.update((k, v) for k, v in kw.items() if k in fields)
+            reqs.append(RAGRequest(**req))
         j = 0
         for b in bursts:
             self.rag.retrieve(reqs[j:j + int(b)])
@@ -346,29 +352,29 @@ def _faults(n: int) -> dict:
                 served[:len(served) - len(served) // 2] + served[:len(served) // 2])}
 
 
-def check_answers(reqs: list, vecs: np.ndarray, predicates, corpus, attrs,
-                  passes, cfg: dict, tr: dict, index_path: str, rng,
+def check_answers(s: Setup, reqs: list, vecs: np.ndarray, predicates, rng,
                   readings: bool) -> dict:
-    """``{"program": numbers}`` of a sample of the answers; with
-    ``readings`` also the control's numbers and those of each fault.
-    ``passes``: the filter schema's."""
+    """``{"program": numbers}`` of a sample of the window's answers
+    (``reqs``; request ``i`` asked for ``vecs[i]`` filtered on
+    ``predicates[i]``); with ``readings`` also the control's numbers and
+    those of each fault."""
     t0 = time.perf_counter()
-    search = cfg["search"]
-    k = int(search["result_k"])
-    ref_index = checkm.reference_index(index_path, corpus, cfg["index"]["r_max"])
+    cfg, corpus = s.cell.config, s.corpus
+    k = int(cfg["search"]["result_k"])
+    sections = index_file.read(s.info["path"], s.plan.SECTIONS)
     answered = [r for r in reqs if r.ok]
-    size = min(int(tr["check_sample"]), len(answered))
+    size = min(int(s.cell.traffic["check_sample"]), len(answered))
     sample = [answered[i] for i in
               np.sort(rng.choice(len(answered), size=size, replace=False))]
     served = [r.ids for r in sample]
     rows = np.asarray([r.index for r in sample], np.int64)
-    s_vecs, s_preds = vecs[rows], [predicates[i] for i in rows]
-    reference = checkm.answers(ref_index, s_vecs, attrs, s_preds, passes, search)
-    truth = checkm.brute_force(corpus, attrs, s_vecs, s_preds, k, passes)
+    s_vecs = vecs[rows]
+    masks = [s.schema.passes(s.attrs, predicates[i]) for i in rows]
+    reference = s.plan.answers(sections, corpus, s_vecs, masks, cfg, "f32")
+    truth = checkm.brute_force(corpus, s_vecs, masks, k)
 
     def numbers(answers_):
-        return checkm.numbers(answers_, reference, truth, corpus, attrs,
-                              s_vecs, s_preds, passes, k)
+        return checkm.numbers(answers_, reference, truth, corpus, s_vecs, masks, k)
 
     out = {"program": dict(numbers(served), unanswered=len(reqs) - len(answered),
                            sample=size)}
@@ -376,8 +382,8 @@ def check_answers(reqs: list, vecs: np.ndarray, predicates, corpus, attrs,
         f"recall@{k} against exact filtered brute force "
         f"{1.0 - out['program']['recall_miss']:.4f}")
     if readings:
-        out["control"] = numbers(checkm.answers(ref_index, s_vecs, attrs, s_preds,
-                                                passes, search, precision="bf16"))
+        out["control"] = numbers(s.plan.answers(sections, corpus, s_vecs, masks, cfg,
+                                                "bf16"))
         out["faults"] = {name: numbers(f(list(served)))
                          for name, f in _faults(corpus.shape[0]).items()}
     return out
@@ -392,18 +398,35 @@ class Setup:
     corpus: np.ndarray
     attrs: object  # the records' attributes, by the filter schema
     schema: object  # the configuration's filter schema (bench/filters/)
+    plan: object  # the configuration's reference plan (bench/reference/plans/)
     stack: Stack
     info: dict  # bench/index.py: cold, path, build_s
     setup_s: float
 
 
-def requests(cell: specm.Cell, corpus: np.ndarray, seed: int, schema):
-    """The seed's request pool: (query vectors, each one's predicate)."""
+def search_config(config: dict):
+    """The engine's ``SearchConfig``, made of the configuration's whole
+    ``search`` group; a key that names no field of it is refused."""
+    from repro.core import SearchConfig
+
+    s = config["search"]
+    unknown = sorted(set(s) - {f.name for f in dataclasses.fields(SearchConfig)})
+    if unknown:
+        raise ValueError(f"unknown search key(s) {unknown} in configuration "
+                         f"{config.get('name')!r}: no field of SearchConfig")
+    return SearchConfig(**s)
+
+
+def requests(cell: specm.Cell, corpus: np.ndarray, attrs, seed: int, schema):
+    """The seed's request pool: (query vectors, each one's predicate).
+    The schema's ``requests`` sees the vectors, the corpus row each was
+    drawn near and the records' attributes."""
     cfg, tr = cell.config, cell.traffic
     rng = datam.streams(seed)
     pool = min(int(tr["pool"]), corpus.shape[0])
-    vecs = datam.queries(tr["query"], corpus, pool, rng["queries"])
-    return vecs, schema.requests(cfg, tr["labels"], pool, rng["requests"])
+    vecs, sources = datam.queries(tr["query"], corpus, pool, rng["queries"])
+    return vecs, schema.requests(cfg, tr["labels"], pool, rng["requests"],
+                                 vecs, sources, attrs)
 
 
 def set_up(name: str, seed: int, seconds: float, *, root: str, cache_dir: str,
@@ -413,27 +436,27 @@ def set_up(name: str, seed: int, seconds: float, *, root: str, cache_dir: str,
     bench = specm.Bench(root)
     cell = bench.cell(name)
     cfg, tr = cell.config, cell.traffic
-    schema = bench.schema(cfg)
+    schema, plan, search = bench.schema(cfg), bench.plan(cfg), search_config(cfg)
     data_rng = datam.streams(cfg["data_seed"])
     corpus = datam.corpus(cfg, data_rng["corpus"], root)
-    attrs = schema.records(cfg, corpus.shape[0], data_rng["labels"])
-    vecs, preds = requests(cell, corpus, seed, schema)
+    attrs = schema.records(cfg, corpus.shape[0], data_rng["labels"], corpus)
+    vecs, preds = requests(cell, corpus, attrs, seed, schema)
     engine, info = indexm.open_engine(cfg, corpus, attrs, schema,
                                       cache_dir=cache_dir, say=say)
-    stack = Stack(engine, cfg, tr, corpus.shape[0], seconds + GRACE_S, schema)
+    stack = Stack(engine, cfg, tr, corpus.shape[0], seconds + GRACE_S, schema, search)
     del engine
     stack.warm(vecs[::-1], preds[::-1], tr["warmup_bursts"], int(tr["max_batch"]))
     stack.calls.clear()
     setup_s = time.perf_counter() - start_time
     say(f"set-up {setup_s:.3f} s "
         f"({'cold: index built' if info['cold'] else 'warm: index loaded'})")
-    return Setup(cell, bench, corpus, attrs, schema, stack, info, setup_s)
+    return Setup(cell, bench, corpus, attrs, schema, plan, stack, info, setup_s)
 
 
 def window(s: Setup, seed: int, seconds: float, trace_dir: str | None):
     """One window of the seed's traffic on a set-up stack: (requests,
     engine calls, trace box, request vectors, their predicates)."""
-    vecs, preds = requests(s.cell, s.corpus, seed, s.schema)
+    vecs, preds = requests(s.cell, s.corpus, s.attrs, seed, s.schema)
     routes = [s.schema.route(s.cell.config, p) for p in preds]
     submit, timeout = s.stack.frontend.submit, seconds + GRACE_S
 
@@ -485,8 +508,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *, devices,
     say("metrics " + json.dumps(metrics))
 
     # the check, on the host, once the program's state is freed
-    numbers = check_answers(reqs, vecs, preds, s.corpus, s.attrs, s.schema.passes,
-                            cfg, tr, s.info["path"], datam.streams(seed)["sample"],
+    numbers = check_answers(s, reqs, vecs, preds, datam.streams(seed)["sample"],
                             readings=False)["program"]
     say("numbers " + json.dumps(numbers))
     limits = cfg["limits"]

@@ -14,6 +14,8 @@ and below them, each named by a configuration or traffic file:
     <root>/bench/corpora/<generator>.py    one corpus generator (``corpus.generator``)
     <root>/bench/filters/<schema>.py       one filter schema (``filter``)
     <root>/bench/loops/<kind>.py           one arrival loop (``loop.kind``)
+    <root>/bench/reference/plans/<plan>.py one reference search (``reference``;
+                                           without the key, ``DEFAULT_PLAN``)
 """
 from __future__ import annotations
 
@@ -24,6 +26,7 @@ import os
 from typing import Callable
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+DEFAULT_PLAN = "walk"
 
 
 def plugin(root: str, folder: str, name: str, what: str):
@@ -33,7 +36,8 @@ def plugin(root: str, folder: str, name: str, what: str):
     if os.path.basename(name) != name or not os.path.isfile(path):
         raise ValueError(f"unknown {what} {name!r}: no file bench/{folder}/{name}.py")
     spec = importlib.util.spec_from_file_location(
-        f"bench_{folder}_" + name.replace(".", "_").replace("-", "_"), path)
+        f"bench_{folder}_{name}".replace("/", "_").replace(".", "_").replace("-", "_"),
+        path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
@@ -101,3 +105,8 @@ class Bench:
     def schema(self, config: dict):
         """The filter schema of a configuration (``bench/filters/``)."""
         return plugin(self.root, "filters", config["filter"], "filter schema")
+
+    def plan(self, config: dict):
+        """The reference search of a configuration (``bench/reference/plans/``)."""
+        return plugin(self.root, "reference/plans", config.get("reference", DEFAULT_PLAN),
+                      "reference plan")

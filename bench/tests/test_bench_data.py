@@ -41,15 +41,15 @@ def test_zipf_labels_follow_their_masses():
     assert p[0] == pytest.approx(0.3414, abs=1e-4)
     assert p[-1] == pytest.approx(0.0341, abs=1e-4)
     cfg = {"labels": {"kind": "zipf", "classes": 10, "alpha": 1.0}}
-    lab = LABEL.records(cfg, 50_000, data.streams(BIG)["labels"])
+    lab = LABEL.records(cfg, 50_000, data.streams(BIG)["labels"], None)
     assert np.bincount(lab, minlength=10)[0] / lab.size == pytest.approx(p[0], abs=0.01)
 
 
 @pytest.mark.parametrize("call", [
     lambda rng: data.queries({"kind": "zipf_centres", "noise": 0.0},
                              np.zeros((8, 4), np.float32), 2, rng),
-    lambda rng: LABEL.requests(CFG, {"kind": "fixed", "label": 1}, 8, rng),
-    lambda rng: LABEL.records({"labels": {"kind": "tags", "classes": 4}}, 8, rng),
+    lambda rng: LABEL.requests(CFG, {"kind": "fixed", "label": 1}, 8, rng, None, None, None),
+    lambda rng: LABEL.records({"labels": {"kind": "tags", "classes": 4}}, 8, rng, None),
 ], ids=["query", "request_label", "record_label"])
 def test_unknown_kinds_are_refused(call):
     with pytest.raises((ValueError, KeyError)):
@@ -61,6 +61,8 @@ def test_unknown_kinds_are_refused(call):
     ("filters", "tags_sparse", "filter schema"),
     ("loops", "bursty", "loop kind"),
     ("loops", "../loop", "loop kind"),
+    ("reference/plans", "postings", "reference plan"),
+    ("reference/plans", "../oracle", "reference plan"),
 ])
 def test_unknown_files_are_refused_by_name(folder, name, what):
     with pytest.raises(ValueError, match=f"unknown {what}"):
@@ -68,7 +70,8 @@ def test_unknown_files_are_refused_by_name(folder, name, what):
 
 
 def test_uniform_request_labels_cover_the_classes():
-    lab = LABEL.requests(CFG, {"kind": "uniform"}, 5000, data.streams(BIG)["requests"])
+    lab = LABEL.requests(CFG, {"kind": "uniform"}, 5000, data.streams(BIG)["requests"],
+                         None, None, None)
     assert set(np.unique(lab)) == set(range(10))
     assert np.bincount(lab).min() > 400
 
@@ -86,3 +89,24 @@ def test_schema_passes_imports_nothing_of_the_program(schema):
     with open(os.path.join(spec.ROOT, "bench", "filters", f"{schema}.py")) as f:
         src = f.read()
     assert "repro" not in src and "jax" not in src
+
+
+def test_queries_come_with_the_rows_they_were_drawn_near():
+    corpus = data.corpus({"n_vectors": 500, "corpus": {"generator": "bigann_like",
+                                                       "dim": 16, "clusters": 4}},
+                         data.streams(BIG)["corpus"])
+    q, rows = data.queries({"kind": "near_corpus", "noise": 0.0}, corpus, 40,
+                           data.streams(BIG)["queries"])
+    assert rows.shape == (40,) and len(set(rows.tolist())) == 40
+    np.testing.assert_array_equal(q, corpus[rows])
+
+
+@pytest.mark.parametrize("plan", sorted(
+    f[:-3] for f in os.listdir(os.path.join(spec.ROOT, "bench", "reference", "plans"))
+    if f.endswith(".py")))
+def test_plan_imports_nothing_of_the_program(plan):
+    with open(os.path.join(spec.ROOT, "bench", "reference", "plans", f"{plan}.py")) as f:
+        src = f.read()
+    assert "repro" not in src and "jax" not in src
+    mod = spec.plugin(spec.ROOT, "reference/plans", plan, "reference plan")
+    assert callable(mod.answers) and all(isinstance(n, str) for n in mod.SECTIONS)

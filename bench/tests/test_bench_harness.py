@@ -1,12 +1,16 @@
 """The harness on the CPU at tiny sizes: no chip means no result; every
-name in BENCHMARK.json resolves to its file; cells and metrics can be
-added as files alone; the index cache key; and whole runs, sound and
-with the timed path broken, whose ``correct`` has to follow."""
+name in BENCHMARK.json resolves to its file; cells and metrics, and a
+configuration with its own reference plan, schema and search setting,
+can be added as files alone; the warm-up sends requests as they are
+routed; the index cache key; and whole runs, sound and with the timed
+path broken, whose ``correct`` has to follow."""
+import dataclasses
 import json
 import os
 import re
 import subprocess
 import sys
+import types
 
 import numpy as np
 import pytest
@@ -49,6 +53,7 @@ def test_every_name_resolves_to_its_file():
         assert cfg["name"] == c["name"]
         assert c["file"].startswith("bench/configs/")
         assert callable(bench.schema(cfg).passes)
+        assert callable(bench.plan(cfg).answers)
         assert callable(specm.plugin(bench.root, "corpora", cfg["corpus"]["generator"],
                                      "corpus generator").make)
     for w in spec["workloads"]:
@@ -94,7 +99,7 @@ def _bits(config, a, b):
     return np.array([(1 << int(a)) | (1 << (config["pair"]["a"] + int(b)))], np.uint32)
 
 
-def records(config, n, rng):
+def records(config, n, rng, corpus):
     p = config["pair"]
     return np.stack([rng.integers(0, p["a"], n), rng.integers(0, p["b"], n)], 1).astype(np.int32)
 
@@ -107,7 +112,8 @@ def key_groups(config):
     return {"filter": "pair", "pair": config["pair"]}
 
 
-requests = lambda config, spec, n, rng: records(config, n, rng)
+def requests(config, spec, n, rng, queries, sources, attrs):
+    return records(config, n, rng, None)
 
 
 def tenants(config):
@@ -211,6 +217,186 @@ def test_a_cell_and_a_metric_added_as_files_alone(tmp_path):
         assert "tags" in s.stack.rag.engine.filters
     finally:
         s.stack.close()
+
+
+# the shape of a filter-track deployment: a reference plan that scans the
+# passing records exactly where they are few, a schema whose records
+# read the corpus and whose requests read each query's source row, and a
+# search setting the program's SearchConfig gains with it
+SCAN_OR_WALK = """\"\"\"scan_or_walk: the passing records scanned exactly where they number
+at most ``search.scan_below``; the default walk otherwise.\"\"\"
+import numpy as np
+
+from bench.reference import oracle
+from bench.reference.plans import walk
+
+SECTIONS = walk.SECTIONS
+
+
+def _scan(corpus, q, mask, k, precision):
+    ids = np.flatnonzero(mask)
+    if precision == "f32":
+        diff = corpus[ids].astype(np.float64) - q.astype(np.float64)
+        d = np.sum(diff * diff, axis=1)
+    else:
+        diff = oracle._bf16(corpus[ids]) - oracle._bf16(q)
+        d = oracle._bf16(np.sum(diff * diff, axis=1, dtype=np.float32))
+    top = ids[np.argsort(d, kind="stable")[:k]]
+    return np.concatenate([top, np.full(k - top.size, -1, np.int64)])
+
+
+def answers(sections, corpus, queries, masks, config, precision):
+    s = config["search"]
+    walked = [i for i, m in enumerate(masks) if m.sum() > int(s["scan_below"])]
+    out = {i: ids for i, ids in zip(walked, walk.answers(
+        sections, corpus, queries[walked], [masks[i] for i in walked], config, precision))}
+    return [out[i] if i in out else _scan(corpus, q, m, int(s["result_k"]), precision)
+            for i, (q, m) in enumerate(zip(queries, masks))]
+"""
+
+TAGGED = """\"\"\"tagged: a record carries the tag of its vector's largest leading
+coordinate; a request asks for the tag of the record its query was
+drawn near.\"\"\"
+import numpy as np
+
+
+def records(config, n, rng, corpus):
+    return np.argmax(corpus[:, :config["tags"]], axis=1).astype(np.int32)
+
+
+def build_args(config, attrs):
+    return {"labels": attrs}
+
+
+def key_groups(config):
+    return {"filter": "tagged", "tags": config["tags"]}
+
+
+def requests(config, spec, n, rng, queries, sources, attrs):
+    return attrs[sources]
+
+
+def tenants(config):
+    return [(f"tag{t}", "label", np.int32(t)) for t in range(config["tags"])]
+
+
+def route(config, predicate):
+    return f"tag{int(predicate)}", {}
+
+
+def passes(attrs, predicate):
+    return attrs == int(predicate)
+"""
+
+
+def test_a_filter_track_configuration_added_as_files_alone(tmp_path, monkeypatch):
+    import jax
+
+    from bench import check, data, run
+    from bench.reference import index_file
+    from repro import core
+
+    root = tiny.make_root(str(tmp_path))
+    cache = os.path.join(root, "cache")
+    _write(root, "bench/reference/plans/scan_or_walk.py", SCAN_OR_WALK)
+    _write(root, "bench/filters/tagged.py", TAGGED)
+    cfg = dict(tiny.config("memory"), name="tiny-tagged", filter="tagged", tags=4,
+               reference="postings")
+    del cfg["labels"]
+    cfg["search"] = dict(cfg["search"], scan_below=2)
+    spec_path = os.path.join(root, "BENCHMARK.json")
+    with open(spec_path) as f:
+        spec = json.load(f)
+    spec["configs"].append({"name": "tiny-tagged", "source": "tests",
+                            "file": "bench/configs/tiny-tagged.json", "reduced": [],
+                            "why": "tests"})
+    spec["workloads"].append({"name": "tiny-tagged.t", "config": "tiny-tagged",
+                              "traffic": "t", "chips": 1, "why": "tests"})
+    with open(spec_path, "w") as f:
+        json.dump(spec, f)
+
+    def set_up(config):
+        _write(root, "bench/configs/tiny-tagged.json", config)
+        return run.set_up("tiny-tagged.t", 2**33 + 9, 1.0, root=root, cache_dir=cache,
+                          start_time=0.0)
+
+    # an unknown plan, then a search key the program's SearchConfig lacks:
+    # each refused by name before any index is built
+    with pytest.raises(ValueError, match="unknown reference plan 'postings'"):
+        set_up(cfg)
+    cfg["reference"] = "scan_or_walk"
+    with pytest.raises(ValueError, match=r"unknown search key\(s\) \['scan_below'\]"):
+        set_up(cfg)
+    assert not os.path.exists(os.path.join(cache, "index"))
+
+    # the program's SearchConfig as the change that serves the scan makes it
+    scan = dataclasses.make_dataclass("SearchConfig", [("scan_below", int, 0)],
+                                      bases=(core.SearchConfig,), frozen=True)
+    monkeypatch.setattr(core, "SearchConfig", scan)
+    out = run.run_cell("tiny-tagged.t", 2**34 + 5, 1.0, False, devices=jax.devices(),
+                       root=root, cache_dir=cache)
+    assert out["correct"], out["checks"]
+    assert out["checks"]["mismatch"]["value"] == 0 and out["failed"] == 0
+    s = set_up(cfg)
+    try:
+        assert s.stack.rag.search_config.scan_below == 2 and not s.info["cold"]
+        assert s.plan.__name__.endswith("scan_or_walk")
+        np.testing.assert_array_equal(s.attrs, np.argmax(s.corpus[:, :4], axis=1))
+        tr = s.cell.traffic
+        vecs, preds = run.requests(s.cell, s.corpus, s.attrs, 2**33 + 9, s.schema)
+        _, sources = data.queries(tr["query"], s.corpus, len(vecs),
+                                  data.streams(2**33 + 9)["queries"])
+        np.testing.assert_array_equal(preds, s.attrs[sources])
+    finally:
+        s.stack.close()
+
+    # the plan's own answers: an exact scan where it scans, the walk where not
+    sections = index_file.read(s.info["path"], s.plan.SECTIONS)
+    q, masks = vecs[:24], [s.schema.passes(s.attrs, p) for p in preds[:24]]
+    truth = check.brute_force(s.corpus, q, masks, 10)
+
+    def plan(scan_below):
+        c = dict(cfg, search=dict(cfg["search"], scan_below=scan_below))
+        return s.plan.answers(sections, s.corpus, q, masks, c, "f32")
+
+    scanned, walked = plan(s.corpus.shape[0]), plan(0)
+    np.testing.assert_array_equal(np.asarray(scanned), truth)
+    np.testing.assert_array_equal(
+        np.asarray(walked), np.asarray(s.bench.plan({}).answers(sections, s.corpus, q, masks,
+                                                                cfg, "f32")))
+    assert check.numbers(scanned, scanned, truth, s.corpus, q, masks, 10)["mismatch"] == 0
+    altered = [np.where(np.arange(10) == 0, (a + 1) % s.corpus.shape[0], a) for a in scanned]
+    assert check.numbers(altered, scanned, truth, s.corpus, q, masks, 10)["mismatch"] > 0
+
+
+def test_warm_up_sends_each_request_as_routed():
+    """A route's keywords that name a ``RAGRequest`` field take the place
+    of the tenant's filter in the requests the warm-up builds."""
+    from bench import run
+
+    def route(config, p):
+        if p % 2:
+            return "all", {"filter_kind": "range", "filter_params": np.float32(p),
+                           "deadline_s": 5.0}
+        return "all", {}
+
+    sent = []
+    fake = types.SimpleNamespace(
+        config={}, schema=types.SimpleNamespace(route=route),
+        filters={"all": ("label", np.int32(7))},
+        rag=types.SimpleNamespace(retrieve=lambda reqs: sent.append(list(reqs)),
+                                  engine=None,
+                                  search_config=types.SimpleNamespace(result_k=10)))
+    vecs = np.arange(12, dtype=np.float32).reshape(6, 2)
+    run.Stack.warm(fake, vecs, [0, 1, 2, 3, 4, 5], [2, 4], 3)
+    assert [len(b) for b in sent] == [2, 4, 1, 2, 3]
+    for i, r in enumerate(sent[0] + sent[1]):
+        np.testing.assert_array_equal(r.query_vec, vecs[i])
+        if i % 2:
+            assert (r.filter_kind, r.filter_params) == ("range", np.float32(i))
+        else:
+            assert (r.filter_kind, int(r.filter_params)) == ("label", 7)
+    assert fake.rag.engine is None
 
 
 @pytest.mark.parametrize("twin,metric", [("frontend.p95_ms.open", "p95_ms"),

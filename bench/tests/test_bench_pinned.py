@@ -1,8 +1,10 @@
 """The two configurations make what they made before their corpus
-generators, filter schema and loops became files of their own: digests
-of the corpus, the records' labels, two seeds' request pools, the
-tenants, the index cache key, and the reference's answers on a small
-index made here in NumPy, all taken before the move."""
+generators, filter schema and loops became files of their own, and
+before the reference search became a plan of its own: digests of the
+corpus, the records' labels, two seeds' request pools, the tenants, the
+index cache key, and the reference's answers on a small index made here
+in NumPy, all taken before the moves; and the default plan answers as
+the oracle does."""
 import hashlib
 import os
 import sys
@@ -56,11 +58,12 @@ def made():
         rng = data.streams(cfg["data_seed"])
         corpus = data.corpus(cfg, rng["corpus"])
         got = {"corpus": corpus,
-               "attrs": schema.records(cfg, corpus.shape[0], rng["labels"]),
+               "attrs": schema.records(cfg, corpus.shape[0], rng["labels"], corpus),
                "key": index.cache_key(cfg, "0123456789abcdef", "None", "0.6.0", "tpu",
                                       schema.key_groups(cfg))}
         for seed in SEEDS:
-            got[f"vecs{seed}"], got[f"preds{seed}"] = run.requests(cell, corpus, seed, schema)
+            got[f"vecs{seed}"], got[f"preds{seed}"] = run.requests(cell, corpus, got["attrs"],
+                                                                   seed, schema)
         out[name] = (cfg, schema, got)
     return out
 
@@ -118,8 +121,29 @@ def test_reference_answers_are_pinned(which):
     schema = spec.Bench().schema({"filter": "label"})
     ix, labels, queries, preds = _tiny_index()
     if which == "brute":
-        got = check.brute_force(ix.vectors, labels, queries, preds, 5, schema.passes)
+        got = check.brute_force(ix.vectors, queries,
+                                [schema.passes(labels, p) for p in preds], 5)
     else:
         got = [oracle.search(ix, q, schema.passes(labels, p), mode="gate", L=16, W=4,
                              K=5, precision=which)[0] for q, p in zip(queries, preds)]
     assert np.asarray(got).tolist() == ANSWERS[which]
+
+
+@pytest.mark.parametrize("precision", ["f32", "bf16"])
+def test_default_plan_answers_as_the_oracle(precision):
+    bench = spec.Bench()
+    plan = bench.plan(bench.config("bigann-label-ssd"))
+    assert plan.__name__.endswith("_walk") and spec.DEFAULT_PLAN == "walk"
+    schema = bench.schema({"filter": "label"})
+    ix, labels, queries, preds = _tiny_index()
+    sections = {"neighbors": ix.neighbors.astype(np.int32), "pq_books": ix.books,
+                "pq_codes": ix.codes.astype(np.uint8), "medoid": ix.entry}
+    config = {"index": {"r_max": ix.r_max},
+              "search": {"mode": "gate", "search_l": 16, "beam_width": 4, "result_k": 5,
+                         "max_hops": 512, "pipeline_depth": 1}}
+    masks = [schema.passes(labels, p) for p in preds]
+    got = plan.answers(sections, ix.vectors, queries, masks, config, precision)
+    want = [oracle.search(ix, q, m, mode="gate", L=16, W=4, K=5, precision=precision)[0]
+            for q, m in zip(queries, masks)]
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert np.asarray(got).tolist() == ANSWERS[precision]

@@ -1,6 +1,6 @@
 """A benchmark root of tiny cells for the CPU tests: the repository's
-traffic mixes, metric readers, corpus generators, filter schemas and
-loops, with configurations small enough to build in seconds."""
+traffic mixes, metric readers, corpus generators, filter schemas, loops
+and reference plans, with configurations small enough to build in seconds."""
 from __future__ import annotations
 
 import json
@@ -64,7 +64,8 @@ def make_root(path: str) -> str:
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         spec = json.load(f)
     bench = os.path.join(path, "bench")
-    for folder in ("metrics", "traffic", "configs", "corpora", "filters", "loops"):
+    for folder in ("metrics", "traffic", "configs", "corpora", "filters", "loops",
+                   "reference/plans"):
         shutil.copytree(os.path.join(REPO, "bench", folder), os.path.join(bench, folder),
                         ignore=shutil.ignore_patterns("__pycache__"))
     for name, traffic in (("t", TRAFFIC), ("o", OPEN_TRAFFIC)):
